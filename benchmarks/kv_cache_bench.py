@@ -121,18 +121,18 @@ def _logit_rollout(cfg, opts, params, prompt, n_steps, kv_dtype,
 
 def _fake_quant_cache(caches, dtype, reduce_axes):
     """Round-trip every dense KV cache leaf through ``dtype`` codes with
-    amax scales at a chosen granularity. Leaves are ``[..., S, K, h]``
-    (token, kv-head, head-dim trailing); the token axis is reshaped to
-    ``(num_pages, PAGE_SIZE)`` so ``reduce_axes`` — relative to the
-    reshaped ``[..., np, ps, K, h]`` — selects the scale granularity:
-    ``(-3, -2, -1)`` per-page, ``(-3, -1)`` per-(page, head) (the pool's
-    format), ``(-1,)`` per-(page, head, token)."""
+    amax scales at a chosen granularity. Leaves are head-major
+    ``[..., K, S, h]`` (kv-head, token, head-dim trailing); the token axis
+    is reshaped to ``(num_pages, PAGE_SIZE)`` so ``reduce_axes`` — relative
+    to the reshaped ``[..., K, np, ps, h]`` — selects the scale
+    granularity: ``(-4, -2, -1)`` per-page, ``(-2, -1)`` per-(page, head)
+    (the pool's format), ``(-1,)`` per-(page, head, token)."""
     def leaf(x):
         if not jnp.issubdtype(x.dtype, jnp.floating) or x.ndim < 3:
             return x
-        S = x.shape[-3]
-        y = x.reshape(x.shape[:-3] + (S // PAGE_SIZE, PAGE_SIZE)
-                      + x.shape[-2:])
+        S = x.shape[-2]
+        y = x.reshape(x.shape[:-2] + (S // PAGE_SIZE, PAGE_SIZE)
+                      + x.shape[-1:])
         a = jnp.max(jnp.abs(y.astype(jnp.float32)), axis=reduce_axes,
                     keepdims=True)
         scale = a / kv_quant.qmax(kv_quant.quant_dtype(dtype))
@@ -166,9 +166,9 @@ def _dense_rollout(cfg, opts, params, prompt, n_steps, quant=None,
     return jnp.stack(out), greedy
 
 
-# (reduce_axes over [..., np, ps, K, h], f32 scale elements per page-head)
-GRANULARITIES = (("per_page", (-3, -2, -1)),
-                 ("per_page_head", (-3, -1)),
+# (reduce_axes over [..., K, np, ps, h], f32 scale elements per page-head)
+GRANULARITIES = (("per_page", (-4, -2, -1)),
+                 ("per_page_head", (-2, -1)),
                  ("per_page_head_token", (-1,)))
 
 

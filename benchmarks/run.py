@@ -19,6 +19,7 @@ from benchmarks import (claims_check, decode_microbench, engine_bench,
                         frontend_bench, kv_cache_bench, perf_compare,
                         roofline_report, scheduler_bench, sharded_bench,
                         spec_decode_bench, table1_hardware)
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = {
     "claims": claims_check,
@@ -52,6 +53,7 @@ def main() -> None:
             print(f"{key:<{width}}  {desc}")
         return
     selected = args.only.split(",") if args.only else list(MODULES)
+    enable_compile_cache()
 
     rows = []
 

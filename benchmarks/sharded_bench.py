@@ -29,23 +29,17 @@ engine; see docs/architecture.md §Sharded serving for the design):
 Reported (not gated): tokens/s per (mode, N) and the projected
 bandwidth-bound tick floor per device. Headline figures land in
 ``BENCH_sharded.json`` (schema in docs/benchmarks.md); ``perf_compare``
-diffs them against the committed baseline. Needs >= 2 visible devices —
-the module requests 8 host-platform CPU devices before jax's backend
-initializes, and skips cleanly if another module got there first.
+diffs them against the committed baseline. Needs >= 2 visible devices
+and fails with fewer; on the CPU give it 8:
+
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python -m benchmarks.run --only sharded
 """
 from __future__ import annotations
 
 import ast
 import json
 import os
-
-# must land before the first jax backend touch; harmless if another bench
-# already initialized the backend (run() skips when devices stay short)
-if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                               + os.environ.get("XLA_FLAGS", ""))
-
 import time
 
 import jax
@@ -147,10 +141,10 @@ def _gate_mesh_blind(emit):
 def run(emit) -> None:
     _gate_mesh_blind(emit)
     if jax.device_count() < 2:
-        emit("sharded/skipped", 0.0,
-             f"needs >=2 devices, have {jax.device_count()}; set XLA_FLAGS="
-             "--xla_force_host_platform_device_count=8")
-        return
+        raise RuntimeError(
+            f"the sharded bench needs >= 2 devices, JAX sees "
+            f"{jax.device_count()}; on the CPU set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count=8")
     sizes = tuple(n for n in MESH_SIZES if n <= jax.device_count())
 
     cfg = get_config(ARCH).reduced()

@@ -161,7 +161,7 @@ def _gate_pool_accounting(cfg, opts, params, emit):
     # masked rows are routed to the null page as zeros, and the null
     # page's codes and scales stay zero
     K, h = cfg.num_kv_heads, cfg.head_dim
-    pages = jnp.zeros((4, PAGE_SIZE, K, h), jnp.int8)
+    pages = jnp.zeros((4, K, PAGE_SIZE, h), jnp.int8)
     scales = jnp.zeros((4, K), jnp.float32)
     pt = jnp.asarray([[1, 2]], jnp.int32)
     rows = jax.random.normal(jax.random.PRNGKey(0), (1, PAGE_SIZE, K, h))
@@ -179,7 +179,7 @@ def _gate_pool_accounting(cfg, opts, params, emit):
         "valid chunk write polluted the null page"
     assert not float(jnp.abs(s3[0]).sum())
     # same contract under per-token scales (the speculative pool layout)
-    st = jnp.zeros((4, PAGE_SIZE, K), jnp.float32)
+    st = jnp.zeros((4, K, PAGE_SIZE), jnp.float32)
     p4, s4 = update_cache_paged_chunk(pages, rows, pt, 0, n_valid=0,
                                       scales=st)
     assert not int(jnp.abs(p4.astype(jnp.int32)).sum()), \

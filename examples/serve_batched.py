@@ -8,11 +8,9 @@ paper's measurement, taken on our own serving engine.
 import argparse
 
 import numpy as np
-import jax
-import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.models import model as M
+from repro.launch.serve import serving_params
 from repro.models.layers import ModelOptions
 from repro.serving import Request, ServingEngine
 
@@ -32,9 +30,6 @@ def main(argv=None):
                    choices=["bf16", "int8", "fp8"],
                    help="paged pool storage (int8/fp8 = quantized pages "
                         "with per-page scales; needs --paged)")
-    p.add_argument("--pallas", action="store_true",
-                   help="route decode through the flash-decode Pallas "
-                        "kernels (interpret mode on CPU: slow, real path)")
     p.add_argument("--chunked-prefill", action="store_true",
                    help="token-budget scheduler: chunked prefill packed "
                         "between decode ticks, prefix hits skip compute")
@@ -60,10 +55,10 @@ def main(argv=None):
     args = p.parse_args(argv)
 
     cfg = get_config("qwen1.5-0.5b").reduced()
-    opts = ModelOptions(remat=False, use_pallas=args.pallas,
-                        prefill_band=args.prefill_band)
-    params = M.init_params(M.model_template(cfg), jax.random.PRNGKey(0),
-                           jnp.float32)
+    # the platform picks the attention cores: compiled Pallas kernels on
+    # the TPU, the jnp cores elsewhere
+    opts = ModelOptions(remat=False, prefill_band=args.prefill_band)
+    params = serving_params(cfg)
     eng = ServingEngine(cfg, opts, params, n_slots=4, max_seq=96, eos=-1,
                         fused=not args.reference,
                         tick_tokens=args.tick_tokens,

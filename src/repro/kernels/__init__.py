@@ -1,5 +1,6 @@
 """Pallas TPU kernels for the compute hot-spots, each validated in
-interpret mode against a pure-jnp oracle (ref.py):
+interpret mode against a pure-jnp oracle (ref.py); the attention kernels of
+the served path also compile for a v5e in tests/test_tpu_compile.py:
 
 - flash_attention: prefill/training attention (causal + sliding window, GQA)
 - decode_attention: flash-decode over the KV cache (the paper's bottleneck),

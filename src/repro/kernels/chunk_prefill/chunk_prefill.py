@@ -1,9 +1,10 @@
 """Banded chunk-prefill Pallas TPU kernel (dense cache view).
 
 Prefill-with-cache attention for one chunk of ``S`` queries written at
-positions ``index .. index+S-1`` against a live KV cache view. The layout
-follows ``decode_attention``: the per-slot start positions arrive as a
-scalar-prefetch operand, the KV-block grid dimension is innermost and
+positions ``index .. index+S-1`` against a live head-major KV cache view
+``[B, K, L, h]``. The layout follows ``decode_attention``: the per-slot
+start positions arrive as a scalar-prefetch operand, the KV-block grid
+dimension is innermost and
 sequential, and online-softmax state lives in VMEM scratch across it.
 Blocks with no unmasked lane for *any* chunk row — past the chunk's last
 position, or entirely older than its sliding window — are skipped twice
@@ -22,10 +23,11 @@ only on the absolute key-block partition up to its own position — never on
 how the prompt was chunked or how much trailing cache view the caller
 passed in.
 
-``_chunk_prefill_body`` is shared with the paged variant (``paged.py``);
-the two kernels differ only in how a KV block is located (contiguous cache
-rows vs a scalar-prefetched page-table gather), so the numerically
-sensitive part lives in exactly one place.
+The online-softmax body is ``decode_attention.flash_block``, shared with
+the decode kernels and the paged variant (``paged.py``): a grid cell's
+query rows are the chunk's S positions of one head (``row_step=1``), and
+the kernels differ only in how a KV block is located (contiguous cache rows
+vs a scalar-prefetched page-table gather).
 """
 from __future__ import annotations
 
@@ -33,141 +35,65 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.base import GLOBAL_WINDOW
-
-NEG_INF = -1e30
-
-
-def _chunk_block_live(index, S: int, k_start, bk: int, window: int):
-    """Whether KV block [k_start, k_start+bk) has any unmasked lane for a
-    chunk of S queries at positions index..index+S-1 (shared by kernel
-    bodies and BlockSpec index maps). The causal bound uses the *youngest*
-    query (index+S-1); the window bound uses the *oldest* (index) — a block
-    too old even for it is too old for every row."""
-    live = k_start <= index + (S - 1)
-    if window != GLOBAL_WINDOW:
-        live = jnp.logical_and(live, (index - (k_start + bk - 1)) < window)
-    return live
-
-
-def _chunk_prefill_body(index, ik, q_ref, k_ref, v_ref, o_ref,
-                        m_scr, l_scr, acc_scr, *, bk: int, nk: int,
-                        window: int, k_scale=None, v_scale=None):
-    """One KV block of the banded chunk online-softmax update. ``index`` is
-    this slot's chunk start position; ``ik`` the block's position in the
-    logical sequence (covering key positions [ik*bk, (ik+1)*bk)). Query row
-    r sits at absolute position index + r. Lanes past a row's position
-    (stale cache rows, or out-of-bounds tail lanes of a non-aligned view)
-    are masked before they can contribute, and V is zeroed on lanes dead
-    for every row so NaN-padded OOB tails cannot poison the accumulator.
-
-    ``k_scale``/``v_scale`` (optional f32 — a scalar per-(page, head)
-    scale, or a [bk, 1] per-token column) dequantize an int8/fp8 KV block
-    inside the VMEM tile (quantized paged pools)."""
-    S = q_ref.shape[1]
-
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    k_start = ik * bk
-
-    @pl.when(_chunk_block_live(index, S, k_start, bk, window))
-    def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)      # [S, h]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [bk, h]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if k_scale is not None:
-            k = k * k_scale
-        if v_scale is not None:
-            v = v * v_scale
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s *= 1.0 / np.sqrt(q.shape[-1])                # [S, bk]
-        q_pos = index + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos <= q_pos
-        if window != GLOBAL_WINDOW:
-            mask &= (q_pos - kpos) < window
-        s = jnp.where(mask, s, NEG_INF)
-        # lanes dead for every row (past the youngest query) may be OOB
-        # tail lanes — NaN-padded in interpret mode, undefined on TPU —
-        # and 0 * NaN would poison the accumulator
-        v = jnp.where((kpos[0, :] <= index + (S - 1))[:, None], v, 0.0)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None]) * mask
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+from repro.kernels.decode_attention.decode_attention import (block_live,
+                                                             flash_block,
+                                                             softmax_scratch)
 
 
 def _kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             bk: int, nk: int, window: int):
-    _chunk_prefill_body(idx_ref[pl.program_id(0)], pl.program_id(2),
-                        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                        bk=bk, nk=nk, window=window)
+    flash_block(idx_ref[pl.program_id(0)], pl.program_id(2),
+                q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                bk=bk, nk=nk, window=window, row_step=1)
 
 
 def chunk_prefill_attention_kernel(q, k_cache, v_cache, index, *,
                                    window: int = GLOBAL_WINDOW, bk: int = 128,
                                    interpret: bool = False):
     """q [B,S,N,h] (one prefill chunk, already written to the cache);
-    k/v cache view [B,L,K,h] (the caller may pre-slice L to the banded
-    live bound — see layers.attention); index: int32 scalar or per-slot [B]
-    vector of chunk start positions. Returns [B,S,N,h].
+    k/v cache view [B,K,L,h] (head-major; the caller may pre-slice L to the
+    banded live bound — see layers.attention); index: int32 scalar or
+    per-slot [B] vector of chunk start positions. Returns [B,S,N,h].
 
     L need not divide by ``bk``: the grid covers ceil(L/bk) blocks and the
     tail block's out-of-bounds lanes carry key positions past every query,
     so the causal mask (and the V zeroing) silently discards them."""
     B, S, N, h = q.shape
-    L, K = k_cache.shape[1], k_cache.shape[2]
+    K, L = k_cache.shape[1], k_cache.shape[2]
     G = N // K
     bk = min(bk, L)
     nk = pl.cdiv(L, bk)
-    grid = (B, N, nk)
+    qt = q.swapaxes(1, 2)                                 # [B, N, S, h]
     idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1), (B,))
+
+    def q_map(b, n, ik, idx_ref):
+        return b, n, 0, 0
 
     def kv_map(b, n, ik, idx_ref):
         # remap fully-dead blocks to block 0 so their DMA is elided
         # (repeated index-map outputs are not re-fetched); compute is
         # pl.when-skipped. GQA: query head n reads KV head n // G.
-        live = _chunk_block_live(idx_ref[b], S, ik * bk, bk, window)
-        return b, jnp.where(live, ik, 0), n // G, 0
+        i = idx_ref[b]
+        live = block_live(i, i + S - 1, ik * bk, bk, window)
+        return b, n // G, jnp.where(live, ik, 0), 0
 
     kernel = functools.partial(_kernel, bk=bk, nk=nk, window=window)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, S, 1, h),
-                             lambda b, n, ik, idx_ref: (b, 0, n, 0)),
-                pl.BlockSpec((1, bk, 1, h), kv_map),
-                pl.BlockSpec((1, bk, 1, h), kv_map),
-            ],
-            out_specs=pl.BlockSpec((1, S, 1, h),
-                                   lambda b, n, ik, idx_ref: (b, 0, n, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((S,), jnp.float32),
-                pltpu.VMEM((S,), jnp.float32),
-                pltpu.VMEM((S, h), jnp.float32),
-            ],
+            grid=(B, N, nk),
+            in_specs=[pl.BlockSpec((1, 1, S, h), q_map),
+                      pl.BlockSpec((1, 1, bk, h), kv_map),
+                      pl.BlockSpec((1, 1, bk, h), kv_map)],
+            out_specs=pl.BlockSpec((1, 1, S, h), q_map),
+            scratch_shapes=softmax_scratch(S, h),
         ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
-    )(idx, q, k_cache, v_cache)
+    )(idx, qt, k_cache, v_cache)
+    return out.swapaxes(1, 2)

@@ -1,24 +1,22 @@
 """Paged banded chunk-prefill Pallas TPU kernel (bf16 or int8/fp8 pages).
 
 Same banded chunk attention as ``chunk_prefill.py`` — the online-softmax
-body is literally shared (``_chunk_prefill_body``) — but the KV cache lives
-in a shared page pool ``[num_pages, page_size, K, h]`` addressed through a
-per-slot page table, exactly like the paged flash-decode kernel
-(``decode_attention/paged.py``). The page table and the per-slot chunk
-start positions arrive as scalar-prefetch operands, so the *index map
-itself* gathers KV pages: grid cell ``(b, head, p)`` DMAs physical page
-``page_table[b, p]`` from HBM — the paged cache view needs **no host-side
-pool gather** (the pre-dispatcher serving path materialized the whole
-``npg * page_size`` dense view per chunk). Pages past the chunk's live
-prefix, or entirely older than its sliding window, are remapped to the
+body is literally shared (``decode_attention.flash_block``) — but the KV
+cache lives in a shared head-major page pool ``[num_pages, K, page_size,
+h]`` addressed through a per-slot page table, exactly like the paged
+flash-decode kernel (``decode_attention/paged.py``). The page table and the
+per-slot chunk start positions arrive as scalar-prefetch operands, so the
+*index map itself* gathers KV pages: grid cell ``(b, head, p)`` DMAs the
+``(page_size, h)`` tile of physical page ``page_table[b, p]`` from HBM — the
+paged cache view needs **no host-side pool gather**. Pages past the chunk's
+live prefix, or entirely older than its sliding window, are remapped to the
 reserved null page 0 so their DMA is never issued, and their compute is
 skipped by ``pl.when``.
 
 Quantized pools (``k_scales``/``v_scales`` given) stream 1-byte codes plus
 one f32 scale array per pool — ``[num_pages, K]`` per-(page, head) or
-``[num_pages, page_size, K]`` per-token, dispatched on ndim — gathered
-through the same page-table index map and dequantized inside the VMEM
-tile, as in the paged decode kernel.
+``[num_pages, K, page_size]`` per-token, read as per-token rows exactly as
+in the paged decode kernel.
 
 Partition caveat: this kernel blocks the key axis per *page* (one grid cell
 per page — a BlockSpec gather cannot span non-contiguous pages), while the
@@ -26,7 +24,7 @@ dense chunk kernel blocks per ``bk``. The blockwise online softmax is only
 bit-stable across dispatches that share one absolute partition, so engines
 that compare paged-kernel streams against dense-kernel streams must run
 with ``page_size == prefill_band`` (``ServingEngine`` enforces this for
-chunked-prefill mode under ``use_pallas``).
+chunked-prefill mode on the kernel path).
 """
 from __future__ import annotations
 
@@ -38,36 +36,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.base import GLOBAL_WINDOW
-from repro.kernels.chunk_prefill.chunk_prefill import (_chunk_block_live,
-                                                       _chunk_prefill_body)
+from repro.kernels.decode_attention.decode_attention import (block_live,
+                                                             flash_block,
+                                                             softmax_scratch)
+from repro.kernels.decode_attention.paged import head_row, token_scales
 
 
 def _paged_kernel(pt_ref, idx_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, ps: int, npg: int, window: int):
-    _chunk_prefill_body(idx_ref[pl.program_id(0)], pl.program_id(2),
-                        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                        bk=ps, nk=npg, window=window)
+    flash_block(idx_ref[pl.program_id(0)], pl.program_id(2),
+                q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                bk=ps, nk=npg, window=window, row_step=1)
 
 
 def _paged_quant_kernel(pt_ref, idx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                         o_ref, m_scr, l_scr, acc_scr, *, ps: int, npg: int,
-                        window: int):
-    _chunk_prefill_body(idx_ref[pl.program_id(0)], pl.program_id(2),
-                        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                        bk=ps, nk=npg, window=window,
-                        k_scale=ks_ref[0, 0], v_scale=vs_ref[0, 0])
-
-
-def _paged_quant_tok_kernel(pt_ref, idx_ref, q_ref, k_ref, v_ref, ks_ref,
-                            vs_ref, o_ref, m_scr, l_scr, acc_scr, *, ps: int,
-                            npg: int, window: int):
-    # per-token scales: one f32 per row of the page, broadcast over h as a
-    # [ps, 1] column against the [ps, h] KV tile
-    _chunk_prefill_body(idx_ref[pl.program_id(0)], pl.program_id(2),
-                        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                        bk=ps, nk=npg, window=window,
-                        k_scale=ks_ref[0, :, 0][:, None],
-                        v_scale=vs_ref[0, :, 0][:, None])
+                        window: int, G: int):
+    kh = pl.program_id(1) // G
+    flash_block(idx_ref[pl.program_id(0)], pl.program_id(2),
+                q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                bk=ps, nk=npg, window=window, row_step=1,
+                k_scale=head_row(ks_ref, kh), v_scale=head_row(vs_ref, kh))
 
 
 def paged_chunk_prefill_attention_kernel(q, k_pages, v_pages, page_table,
@@ -76,77 +65,61 @@ def paged_chunk_prefill_attention_kernel(q, k_pages, v_pages, page_table,
                                          window: int = GLOBAL_WINDOW,
                                          interpret: bool = False):
     """q [B,S,N,h] (one prefill chunk, already scattered into the pool);
-    k/v pages [num_pages, page_size, K, h] (bf16/f32, or int8/fp8 codes
+    k/v pages [num_pages, K, page_size, h] (bf16/f32, or int8/fp8 codes
     when ``k_scales``/``v_scales`` f32 — ``[num_pages, K]`` per-(page,
-    head) or ``[num_pages, page_size, K]`` per-token, dispatched on ndim —
-    are given; pass both or neither); page_table [B, npg] int32 physical
-    page ids (the caller
+    head) or ``[num_pages, K, page_size]`` per-token — are given; pass both
+    or neither); page_table [B, npg] int32 physical page ids (the caller
     may pre-slice npg to the banded live bound); index int32 scalar or
     per-slot [B] vector of chunk start positions. Returns [B,S,N,h]."""
     if (k_scales is None) != (v_scales is None):
         raise ValueError("pass both k_scales and v_scales, or neither")
     B, S, N, h = q.shape
-    ps, K = k_pages.shape[1], k_pages.shape[2]
+    K, ps = k_pages.shape[1], k_pages.shape[2]
     npg = page_table.shape[1]
     G = N // K
-    grid = (B, N, npg)
+    qt = q.swapaxes(1, 2)                                 # [B, N, S, h]
     pt = jnp.asarray(page_table, jnp.int32)
     idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1), (B,))
 
-    def kv_map(b, n, ip, pt_ref, idx_ref):
+    def page(b, ip, pt_ref, idx_ref):
         # gather through the page table; dead pages remap to the null page
         # so their distinct-page DMA is never issued
-        live = _chunk_block_live(idx_ref[b], S, ip * ps, ps, window)
-        return jnp.where(live, pt_ref[b, ip], 0), 0, n // G, 0
+        i = idx_ref[b]
+        live = block_live(i, i + S - 1, ip * ps, ps, window)
+        return jnp.where(live, pt_ref[b, ip], 0)
+
+    def q_map(b, n, ip, pt_ref, idx_ref):
+        return b, n, 0, 0
+
+    def kv_map(b, n, ip, pt_ref, idx_ref):
+        return page(b, ip, pt_ref, idx_ref), n // G, 0, 0
 
     def scale_map(b, n, ip, pt_ref, idx_ref):
-        # per-(page, head) scale block, remapped in lockstep with kv_map
-        live = _chunk_block_live(idx_ref[b], S, ip * ps, ps, window)
-        return jnp.where(live, pt_ref[b, ip], 0), n // G
+        return page(b, ip, pt_ref, idx_ref), 0, 0
 
-    def scale_map_tok(b, n, ip, pt_ref, idx_ref):
-        # per-token scale block: the page's [ps] scale column for this head
-        live = _chunk_block_live(idx_ref[b], S, ip * ps, ps, window)
-        return jnp.where(live, pt_ref[b, ip], 0), 0, n // G
-
-    q_spec = pl.BlockSpec((1, S, 1, h),
-                          lambda b, n, ip, pt_ref, idx_ref: (b, 0, n, 0))
-    in_specs = [q_spec,
-                pl.BlockSpec((1, ps, 1, h), kv_map),
-                pl.BlockSpec((1, ps, 1, h), kv_map)]
-    operands = [q, k_pages, v_pages]
+    in_specs = [pl.BlockSpec((1, 1, S, h), q_map),
+                pl.BlockSpec((1, 1, ps, h), kv_map),
+                pl.BlockSpec((1, 1, ps, h), kv_map)]
+    operands = [qt, k_pages, v_pages]
     if k_scales is None:
         kernel = functools.partial(_paged_kernel, ps=ps, npg=npg,
                                    window=window)
-    elif k_scales.ndim == 3:
-        kernel = functools.partial(_paged_quant_tok_kernel, ps=ps, npg=npg,
-                                   window=window)
-        in_specs += [pl.BlockSpec((1, ps, 1), scale_map_tok),
-                     pl.BlockSpec((1, ps, 1), scale_map_tok)]
-        operands += [jnp.asarray(k_scales, jnp.float32),
-                     jnp.asarray(v_scales, jnp.float32)]
     else:
         kernel = functools.partial(_paged_quant_kernel, ps=ps, npg=npg,
-                                   window=window)
-        in_specs += [pl.BlockSpec((1, 1), scale_map),
-                     pl.BlockSpec((1, 1), scale_map)]
-        operands += [jnp.asarray(k_scales, jnp.float32),
-                     jnp.asarray(v_scales, jnp.float32)]
-    return pl.pallas_call(
+                                   window=window, G=G)
+        in_specs += [pl.BlockSpec((1, K, ps), scale_map),
+                     pl.BlockSpec((1, K, ps), scale_map)]
+        operands += [token_scales(k_scales, ps), token_scales(v_scales, ps)]
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
+            grid=(B, N, npg),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, S, 1, h),
-                                   lambda b, n, ip, pt_ref, idx_ref:
-                                   (b, 0, n, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((S,), jnp.float32),
-                pltpu.VMEM((S,), jnp.float32),
-                pltpu.VMEM((S, h), jnp.float32),
-            ],
+            out_specs=pl.BlockSpec((1, 1, S, h), q_map),
+            scratch_shapes=softmax_scratch(S, h),
         ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
     )(pt, idx, *operands)
+    return out.swapaxes(1, 2)

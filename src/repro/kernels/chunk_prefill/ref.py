@@ -16,14 +16,14 @@ from repro.kernels.decode_attention.ref import gather_dequant
 
 def chunk_prefill_ref(q, k_cache, v_cache, index,
                       window: int = GLOBAL_WINDOW):
-    """q [B,S,N,h]; cache view [B,L,K,h]; index scalar or per-slot [B]
-    vector of chunk start positions (query row r of slot b sits at absolute
-    position index[b] + r). Returns [B,S,N,h]."""
+    """q [B,S,N,h]; head-major cache view [B,K,L,h]; index scalar or
+    per-slot [B] vector of chunk start positions (query row r of slot b
+    sits at absolute position index[b] + r). Returns [B,S,N,h]."""
     B, S, N, h = q.shape
-    L, K = k_cache.shape[1], k_cache.shape[2]
+    K, L = k_cache.shape[1], k_cache.shape[2]
     G = N // K
     qg = (q * (1.0 / np.sqrt(h))).reshape(B, S, K, G, h)
-    s = jnp.einsum("bskgh,btkh->bkgst", qg, k_cache).astype(jnp.float32)
+    s = jnp.einsum("bskgh,bkth->bkgst", qg, k_cache).astype(jnp.float32)
     idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1), (B,))
     q_pos = idx[:, None] + jnp.arange(S)                    # [B, S]
     kpos = jnp.arange(L)
@@ -32,7 +32,7 @@ def chunk_prefill_ref(q, k_cache, v_cache, index,
         mask &= (q_pos[..., None] - kpos[None, None]) < window
     s = jnp.where(mask[:, None, None], s, -1e30)
     w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgst,btkh->bkgsh", w, v_cache)
+    out = jnp.einsum("bkgst,bkth->bkgsh", w, v_cache)
     return jnp.moveaxis(out, (1, 2), (2, 3)).reshape(B, S, N, h)
 
 
@@ -40,9 +40,9 @@ def paged_chunk_prefill_ref(q, k_pages, v_pages, page_table, index,
                             window: int = GLOBAL_WINDOW,
                             k_scales=None, v_scales=None):
     """Oracle for the paged kernel: gather the slot's pages (and, for
-    quantized pools, their per-page-per-head scales) into the dense view,
-    dequantize, then run the dense oracle. q [B,S,N,h]; pages
-    [num_pages, page_size, K, h]; page_table [B, npg]; index scalar or
-    [B]; scales [num_pages, K] f32 or None."""
+    quantized pools, their scales) into the dense view, dequantize, then
+    run the dense oracle. q [B,S,N,h]; pages [num_pages, K, page_size, h];
+    page_table [B, npg]; index scalar or [B]; scales [num_pages, K] or
+    [num_pages, K, page_size] f32, or None."""
     kd, vd = gather_dequant(k_pages, v_pages, page_table, k_scales, v_scales)
     return chunk_prefill_ref(q, kd, vd, index, window=window)

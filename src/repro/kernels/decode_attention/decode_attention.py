@@ -2,10 +2,12 @@
 
 Single-token GQA attention against a long KV cache. This op is memory-bound
 (intensity ~= 1 FLOP/byte « v5e ridge of 240), so the kernel is laid out for
-*bandwidth*: the KV cache streams HBM->VMEM in (bk, h) tiles; all G query
-heads of a KV group ride along each tile (one cache read serves G heads, the
-GQA arithmetic-intensity win). Online softmax state lives in VMEM scratch
-across the sequential KV-block grid dimension.
+*bandwidth*: the head-major KV cache ``[B, K, S, h]`` streams HBM->VMEM in
+``(bk, h)`` tiles — positions on the sublane axis, the head dim on the lane
+axis, the TPU's native tiling — and all G query heads of a KV group ride
+along each tile (one cache read serves G heads, the GQA arithmetic-intensity
+win). Online softmax state lives in VMEM scratch across the sequential
+KV-block grid dimension.
 
 The valid length (current decode position) arrives as a scalar-prefetch
 operand, so fully-invalid KV blocks are skipped twice over: the BlockSpec
@@ -15,10 +17,12 @@ paged decode kernel does on GPU, re-expressed for the TPU's sequential grid.
 ``index`` may be a scalar or a per-slot ``[B]`` vector (continuous
 batching): each batch row masks and early-exits against its own position.
 
-``_flash_decode_body`` is the single online-softmax body shared with the
-paged variant (``paged.py``) — the two kernels differ only in how the KV
-block for a grid cell is located (contiguous rows vs page-table gather), so
-the numerically-sensitive part lives in exactly one place.
+``flash_block`` is the single online-softmax body shared by every decode and
+chunk-prefill kernel (dense and paged, ``chunk_prefill/``): the kernels
+differ only in how the KV block for a grid cell is located (contiguous rows
+vs page-table gather) and in where their query rows sit (decode: G heads at
+one position; chunk: S positions of one head), so the numerically-sensitive
+part lives in exactly one place.
 """
 from __future__ import annotations
 
@@ -35,29 +39,43 @@ from repro.configs.base import GLOBAL_WINDOW
 NEG_INF = -1e30
 
 
-def _block_live(index, k_start: int, bk: int, window: int):
-    """Whether KV block [k_start, k_start+bk) has any unmasked position for
-    a query at ``index`` (shared by kernel bodies and BlockSpec index maps)."""
-    live = k_start <= index
+def block_live(index, last, k_start, bk: int, window: int):
+    """Whether KV block [k_start, k_start+bk) has any unmasked lane for
+    query rows at positions index..last (shared by kernel bodies and
+    BlockSpec index maps). The causal bound uses the youngest row
+    (``last``); the window bound uses the oldest (``index``) — a block too
+    old even for it is too old for every row."""
+    live = k_start <= last
     if window != GLOBAL_WINDOW:
         live = jnp.logical_and(live, (index - (k_start + bk - 1)) < window)
     return live
 
 
-def _flash_decode_body(index, ik, q_ref, k_ref, v_ref, o_ref,
-                       m_scr, l_scr, acc_scr, *, bk: int, nk: int,
-                       window: int, k_scale=None, v_scale=None):
-    """One KV block of the online-softmax flash-decode update. ``index`` is
-    this row's current position; ``ik`` the block's position in the logical
-    sequence (block covers positions [ik*bk, (ik+1)*bk)). Positions past
-    ``index`` (including any out-of-bounds tail lanes of a non-aligned
-    cache) are masked before they can contribute.
+def flash_block(index, ik, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                *, bk: int, nk: int, window: int, row_step: int,
+                k_scale=None, v_scale=None):
+    """One KV block of the online-softmax update. ``q_ref``/``o_ref`` are
+    ``[1, 1, R, h]`` blocks of R query rows; row r sits at absolute
+    position ``index + row_step * r`` (decode: ``row_step=0``, every row is
+    one head at the same position; chunk prefill: ``row_step=1``, every row
+    is one position of one head). ``k_ref``/``v_ref`` are ``[1, 1, bk, h]``
+    head-major blocks covering key positions [ik*bk, (ik+1)*bk). Lanes past
+    a row's position (stale cache rows, or out-of-bounds tail lanes of a
+    non-aligned cache) are masked before they can contribute, and V is
+    zeroed on lanes dead for every row so NaN-padded out-of-bounds tails
+    cannot poison the accumulator.
 
-    ``k_scale``/``v_scale`` (optional f32 — a scalar per-(page, head)
-    scale, or a [bk, 1] per-token column that broadcasts over the head
-    dim) dequantize an int8/fp8 KV block inside the VMEM tile: the block's
-    codes are multiplied by the scale right after the fp32 upcast, so HBM
-    only ever streams 1-byte codes and the online softmax still runs in
+    Bit-stability contract: the update for a block that is fully masked for
+    a row is an *exact* no-op for it (``corr == exp(0) == 1``, ``p == 0``),
+    so a row's result depends only on the absolute key-block partition up to
+    its own position — never on how a prompt was chunked or how much
+    trailing cache view the caller passed in.
+
+    ``k_scale``/``v_scale`` (optional f32 ``[1, bk]`` rows, one scale per
+    key position) dequantize an int8/fp8 block: a per-position K scale
+    multiplies that position's score column, and a per-position V scale
+    multiplies the matching probability column before the PV product, so
+    HBM only ever streams 1-byte codes and the online softmax runs in
     fp32."""
     @pl.when(ik == 0)
     def _init():
@@ -66,55 +84,69 @@ def _flash_decode_body(index, ik, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     k_start = ik * bk
+    rows = q_ref.shape[2]
+    last = index + row_step * (rows - 1)
 
-    @pl.when(_block_live(index, k_start, bk, window))
+    @pl.when(block_live(index, last, k_start, bk, window))
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)      # [G, h]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [bk, h]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if k_scale is not None:
-            k = k * k_scale
-        if v_scale is not None:
-            v = v * v_scale
+        q = q_ref[0, 0].astype(jnp.float32)            # [R, h]
+        k = k_ref[0, 0].astype(jnp.float32)            # [bk, h]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s *= 1.0 / np.sqrt(q.shape[-1])                # [G, bk]
+        s = s * (1.0 / np.sqrt(q.shape[-1]))           # [R, bk]
+        if k_scale is not None:
+            s = s * k_scale
+        q_pos = index + row_step * jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos <= index
+        mask = kpos <= q_pos
         if window != GLOBAL_WINDOW:
-            mask &= (index - kpos) < window
+            mask &= (q_pos - kpos) < window
         s = jnp.where(mask, s, NEG_INF)
-        # invalid lanes have p == 0 exactly, but v there may be garbage —
-        # out-of-bounds tail lanes are NaN-padded in interpret mode and
-        # undefined on TPU, and 0 * NaN would poison the accumulator
-        v = jnp.where(mask[0, :, None], v, 0.0)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None]) * mask
+        # lanes dead for every row may be out-of-bounds tail lanes —
+        # NaN-padded in interpret mode, undefined on TPU — and 0 * NaN
+        # would poison the accumulator
+        kcol = k_start + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+        v = jnp.where(kcol <= last, v, 0.0)
+        m_prev = m_scr[...]                            # [R, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new) * mask
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
+        if v_scale is not None:
+            p = p * v_scale
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
+def softmax_scratch(rows: int, h: int):
+    """VMEM scratch for ``flash_block``: running max, running sum, and the
+    unnormalized output accumulator of R query rows."""
+    return [pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, h), jnp.float32)]
 
 
 def _kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             bk: int, nk: int, window: int):
-    _flash_decode_body(idx_ref[pl.program_id(0)], pl.program_id(2),
-                       q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                       bk=bk, nk=nk, window=window)
+    flash_block(idx_ref[pl.program_id(0)], pl.program_id(2),
+                q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                bk=bk, nk=nk, window=window, row_step=0)
 
 
 def decode_attention_kernel(q, k_cache, v_cache, index, *,
                             window: int = GLOBAL_WINDOW, bk: int = 512,
                             interpret: bool = False):
-    """q [B,N,h]; k/v cache [B,S,K,h]; index: int32 scalar or per-slot [B]
-    vector of current positions (each must be < S). Returns [B,N,h].
+    """q [B,N,h]; k/v cache [B,K,S,h] (head-major); index: int32 scalar or
+    per-slot [B] vector of current positions (each must be < S). Returns
+    [B,N,h].
 
     S need not divide by ``bk``: the grid covers ceil(S/bk) blocks and the
     tail block's out-of-bounds lanes carry positions > index, so the
@@ -122,43 +154,39 @@ def decode_attention_kernel(q, k_cache, v_cache, index, *,
     dropped and no padded copy of the cache is materialized.
     """
     B, N, h = q.shape
-    S, K = k_cache.shape[1], k_cache.shape[2]
+    K, S = k_cache.shape[1], k_cache.shape[2]
     G = N // K
     bk = min(bk, S)
     nk = pl.cdiv(S, bk)
-    grid = (B, K, nk)
-    # view q as [B, G, K, h] so one grid cell covers a whole KV group
-    qg = q.reshape(B, K, G, h).swapaxes(1, 2)
+    # view q as [B, K, G, h] so one grid cell covers a whole KV group
+    # (head n = k*G + g)
+    qg = q.reshape(B, K, G, h)
     idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1), (B,))
+
+    def q_map(b, kh, ik, idx_ref):
+        return b, kh, 0, 0
 
     def kv_map(b, kh, ik, idx_ref):
         # remap fully-invalid blocks (past the position, or entirely older
         # than the window) to block 0 so their DMA is elided (repeated
         # index-map outputs are not re-fetched); compute is pl.when-skipped.
-        live = _block_live(idx_ref[b], ik * bk, bk, window)
-        return b, jnp.where(live, ik, 0), kh, 0
+        i = idx_ref[b]
+        live = block_live(i, i, ik * bk, bk, window)
+        return b, kh, jnp.where(live, ik, 0), 0
 
     kernel = functools.partial(_kernel, bk=bk, nk=nk, window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, G, 1, h), lambda b, kh, ik, idx_ref: (b, 0, kh, 0)),
-                pl.BlockSpec((1, bk, 1, h), kv_map),
-                pl.BlockSpec((1, bk, 1, h), kv_map),
-            ],
-            out_specs=pl.BlockSpec((1, G, 1, h),
-                                   lambda b, kh, ik, idx_ref: (b, 0, kh, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G, h), jnp.float32),
-            ],
+            grid=(B, K, nk),
+            in_specs=[pl.BlockSpec((1, 1, G, h), q_map),
+                      pl.BlockSpec((1, 1, bk, h), kv_map),
+                      pl.BlockSpec((1, 1, bk, h), kv_map)],
+            out_specs=pl.BlockSpec((1, 1, G, h), q_map),
+            scratch_shapes=softmax_scratch(G, h),
         ),
-        out_shape=jax.ShapeDtypeStruct((B, G, K, h), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, G, h), q.dtype),
         interpret=interpret,
     )(idx, qg, k_cache, v_cache)
-    # [B,G,K,h] -> head n = k*G + g
-    return out.swapaxes(1, 2).reshape(B, N, h)
+    return out.reshape(B, N, h)

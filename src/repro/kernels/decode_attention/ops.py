@@ -16,7 +16,7 @@ from repro.kernels.decode_attention.paged import (
 def decode_attention(q, k_cache, v_cache, index, *,
                      window: int = GLOBAL_WINDOW, bk: int = 512,
                      interpret: bool = False):
-    """Single-token flash-decode. q [B,N,h]; caches [B,S,K,h]; index int32
+    """Single-token flash-decode. q [B,N,h]; caches [B,K,S,h]; index int32
     position of the token being decoded — scalar or per-slot [B] vector
     (continuous batching). S that does not divide by bk is handled by a
     ceil-divided grid whose out-of-bounds tail lanes are masked in-kernel
@@ -31,11 +31,11 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, index, *,
                            window: int = GLOBAL_WINDOW,
                            interpret: bool = False):
     """Single-token flash-decode against a paged KV pool. q [B,N,h]; pages
-    [num_pages, page_size, K, h]; page_table [B, npg] int32; index scalar or
+    [num_pages, K, page_size, h]; page_table [B, npg] int32; index scalar or
     per-slot [B] vector of current positions. For quantized (int8/fp8)
-    pools pass the sibling per-page-per-head scales ``k_scales``/``v_scales``
-    [num_pages, K] f32 — the kernel gathers them through the same page-table
-    index map and dequantizes inside the VMEM tile."""
+    pools pass the sibling scales ``k_scales``/``v_scales`` ([num_pages, K]
+    or [num_pages, K, page_size] f32) — the kernel gathers them through the
+    same page-table index map and dequantizes inside the VMEM tile."""
     return paged_decode_attention_kernel(q, k_pages, v_pages, page_table,
                                          index, k_scales=k_scales,
                                          v_scales=v_scales, window=window,

@@ -8,6 +8,8 @@ the ~triangular (or banded) set of block pairs, matching the useful-FLOP
 count rather than the naive S^2.
 
 GQA is folded into the index maps: query head n reads KV head n // (N/K).
+The wrapper moves heads ahead of positions (``[B, N, S, h]``), so every
+block is a ``(rows, h)`` tile on the TPU's native (sublane, lane) tiling.
 """
 from __future__ import annotations
 
@@ -20,6 +22,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.base import GLOBAL_WINDOW
+from repro.kernels.decode_attention.decode_attention import softmax_scratch
 
 NEG_INF = -1e30
 
@@ -47,9 +50,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)      # [bq, h]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [bk, h]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)            # [bq, h]
+        k = k_ref[0, 0].astype(jnp.float32)            # [bk, h]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s *= 1.0 / np.sqrt(q.shape[-1])
@@ -61,19 +64,19 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         if window != GLOBAL_WINDOW:
             mask &= (qpos - kpos) < window
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None]) * mask         # kill fully-masked rows
+        m_prev = m_scr[...]                            # [bq, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new) * mask                  # kill fully-masked rows
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_kernel(q, k, v, *, window: int = GLOBAL_WINDOW,
@@ -89,20 +92,17 @@ def flash_attention_kernel(q, k, v, *, window: int = GLOBAL_WINDOW,
 
     kernel = functools.partial(_kernel, bq=bq, bk=bk, nk=nk,
                                window=window, causal=causal)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, h), lambda b, n, iq, ik: (b, iq, n, 0)),
-            pl.BlockSpec((1, bk, 1, h), lambda b, n, iq, ik: (b, ik, n // G, 0)),
-            pl.BlockSpec((1, bk, 1, h), lambda b, n, iq, ik: (b, ik, n // G, 0)),
+            pl.BlockSpec((1, 1, bq, h), lambda b, n, iq, ik: (b, n, iq, 0)),
+            pl.BlockSpec((1, 1, bk, h), lambda b, n, iq, ik: (b, n // G, ik, 0)),
+            pl.BlockSpec((1, 1, bk, h), lambda b, n, iq, ik: (b, n // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, h), lambda b, n, iq, ik: (b, iq, n, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),      # m
-            pltpu.VMEM((bq,), jnp.float32),      # l
-            pltpu.VMEM((bq, h), jnp.float32),    # acc
-        ],
+        out_specs=pl.BlockSpec((1, 1, bq, h), lambda b, n, iq, ik: (b, n, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, N, S, h), q.dtype),
+        scratch_shapes=softmax_scratch(bq, h),
         interpret=interpret,
-    )(q, k, v)
+    )(q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2))
+    return out.swapaxes(1, 2)

@@ -1,5 +1,11 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# an ahead-of-time tool: it compiles for 512 placeholder host devices, so it
+# pins the CPU platform (on a TPU host the production mesh would meet the
+# local chips) and appends its device count to flags set from outside
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(
+    f for f in (os.environ.get("XLA_FLAGS"),
+                "--xla_force_host_platform_device_count=512") if f)
 
 """Multi-pod dry-run: AOT lower + compile every (arch x shape) cell on the
 production mesh, proving the distribution config is coherent, and record
@@ -8,9 +14,10 @@ memory_analysis / cost_analysis / collective traffic for §Roofline.
     PYTHONPATH=src python -m repro.launch.dryrun --arch gemma3-27b \
         --shape train_4k [--multi-pod] [--out artifacts/dryrun]
 
-The XLA_FLAGS line above MUST stay the first statement: jax locks the
-device count on first init, and the dry-run needs 512 placeholder host
-devices. Smoke tests and benchmarks import repro.* directly and see 1.
+The environment lines above MUST stay the first statements: jax locks the
+platform and device count on first init, and the dry-run needs 512
+placeholder host devices. Tests and benchmarks import repro.* directly and
+see their own platform.
 """
 import argparse
 import dataclasses

@@ -6,12 +6,9 @@ import jax
 
 
 def _auto_kwargs(n):
-    # jax.sharding.AxisType only exists on newer jax; older versions get the
-    # default (equivalent) auto axis behaviour with no kwarg at all.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+    # shardings inside the programs are propagated by the compiler (Auto),
+    # not carried in the types (Explicit)
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def _validate_axes(**sizes):

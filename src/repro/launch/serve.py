@@ -25,13 +25,16 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.configs import get_config
 from repro.core.workload import fleet_trace
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_serving_mesh
 from repro.models import model as M
 from repro.models.layers import ModelOptions
 from repro.serving import AsyncFrontend, Backpressure, Request, ServingEngine
+from repro.serving.engine import serving_param_specs
 
 
 def _engine_snapshot(eng):
@@ -58,7 +61,43 @@ def _dump_stats(path: str, snap):
     print(f"[serve] stats snapshot -> {path} ({len(snap)} keys)")
 
 
-def main(argv=None):
+def serving_params(cfg, seed: int = 0, mesh=None):
+    """Random bf16 weights (the model's published dtype) drawn from
+    ``seed``. Under a ``model=N`` mesh each device draws only its own shard
+    of the serving layout (``serving_param_specs``), so no device ever
+    holds the whole tree."""
+    shardings = None
+    if mesh is not None:
+        shardings = jax.tree_util.tree_map(
+            lambda sp: NamedSharding(mesh, sp), serving_param_specs(cfg, mesh))
+    return M.init_params(M.model_template(cfg), jax.random.PRNGKey(seed),
+                         jnp.bfloat16, shardings=shardings)
+
+
+def build_engine(cfg, params, args, mesh=None) -> ServingEngine:
+    """The serving engine ``args`` (this module's ``build_parser()``)
+    describe. Whether attention runs the Pallas kernels follows from the
+    platform: compiled on the TPU, the jnp cores on the CPU."""
+    opts = ModelOptions(remat=False, prefill_band=args.prefill_band)
+    return ServingEngine(cfg, opts, params, n_slots=args.slots, mesh=mesh,
+                         max_seq=args.max_seq, eos=-1,
+                         fused=not args.reference,
+                         tick_tokens=args.tick_tokens,
+                         paged=args.paged, page_size=args.page_size,
+                         num_pages=args.num_pages or None,
+                         kv_dtype=args.kv_dtype,
+                         chunked_prefill=args.chunked_prefill,
+                         chunk_size=args.chunk_size,
+                         token_budget=args.token_budget,
+                         spec_decode=args.spec_decode,
+                         spec_k=args.spec_k,
+                         draft_layers=args.draft_layers or None,
+                         draft_quant=(None if args.draft_quant == "none"
+                                      else args.draft_quant),
+                         slo_hz=args.slo_hz)
+
+
+def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="qwen1.5-0.5b")
     p.add_argument("--requests", type=int, default=8)
@@ -83,8 +122,11 @@ def main(argv=None):
                    help="paged KV cache (shared page pool + per-slot page "
                         "tables, prefix caching) instead of dense per-slot "
                         "buffers")
-    p.add_argument("--page-size", type=int, default=16,
-                   help="tokens per KV page (paged mode)")
+    p.add_argument("--page-size", type=int, default=32,
+                   help="tokens per KV page (paged mode). On the kernel "
+                        "path chunked prefill needs it equal to "
+                        "--prefill-band, and int8 pages need a multiple "
+                        "of 32 (the TPU's int8 sublane tile)")
     p.add_argument("--num-pages", type=int, default=0,
                    help="pool capacity in pages (0 = worst-case sizing)")
     p.add_argument("--kv-dtype", default="bf16",
@@ -93,11 +135,6 @@ def main(argv=None):
                         "dtype; int8/fp8 store 1-byte codes with per-page "
                         "scales, shrinking cache_bytes_hwm and decode HBM "
                         "traffic (requires --paged)")
-    p.add_argument("--pallas", action="store_true",
-                   help="route decode through the flash-decode Pallas "
-                        "kernels (dense or paged per --paged); on CPU they "
-                        "run in interpret mode, which is slow but exercises "
-                        "the real kernel path")
     p.add_argument("--chunked-prefill", action="store_true",
                    help="token-budget scheduler: prompts prefill in fixed "
                         "chunks packed between decode ticks instead of "
@@ -185,37 +222,21 @@ def main(argv=None):
                    help="front-end admission slots per replica reserved "
                         "for realtime traffic: best-effort admits against "
                         "queue-limit minus this (requires --frontend)")
-    args = p.parse_args(argv)
+    return p
 
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    opts = ModelOptions(remat=False, use_pallas=args.pallas,
-                        prefill_band=args.prefill_band)
-    params = M.init_params(M.model_template(cfg), jax.random.PRNGKey(0),
-                           jnp.float32)
-
     mesh = (make_serving_mesh(args.mesh_model)
             if args.mesh_model > 1 else None)
+    params = serving_params(cfg, mesh=mesh)
 
     def make_engine():
-        return ServingEngine(cfg, opts, params, n_slots=args.slots,
-                             mesh=mesh,
-                             max_seq=args.max_seq, eos=-1,
-                             fused=not args.reference,
-                             tick_tokens=args.tick_tokens,
-                             paged=args.paged, page_size=args.page_size,
-                             num_pages=args.num_pages or None,
-                             kv_dtype=args.kv_dtype,
-                             chunked_prefill=args.chunked_prefill,
-                             chunk_size=args.chunk_size,
-                             token_budget=args.token_budget,
-                             spec_decode=args.spec_decode,
-                             spec_k=args.spec_k,
-                             draft_layers=args.draft_layers or None,
-                             draft_quant=(None if args.draft_quant == "none"
-                                          else args.draft_quant),
-                             slo_hz=args.slo_hz)
+        return build_engine(cfg, params, args, mesh)
 
     if args.frontend:
         return asyncio.run(_main_frontend(args, cfg, make_engine))

@@ -9,16 +9,16 @@ paged flash-decode kernel must move HBM->VMEM.
 Format
 ------
 - ``kv_dtype`` names the pool storage: ``"bf16"`` (unquantized — pages keep
-  the cache dtype the caller picks, f32 in the serving engine so the
-  paged-vs-dense bit-equality oracle holds), ``"int8"`` (symmetric, codes in
+  the cache dtype the caller picks; the serving engine uses the params'
+  dtype), ``"int8"`` (symmetric, codes in
   [-127, 127]) or ``"fp8"`` (``float8_e4m3fn``, max 448).
-- Every quantized K/V pool leaf ``[num_pages, page_size, K, h]`` gets a
+- Every quantized K/V pool leaf ``[num_pages, K, page_size, h]`` gets a
   sibling float32 scale leaf whose shape is set by the pool's **scale
   granularity**: ``"head"`` stores ``[num_pages, K]`` (per-page,
   per-KV-head — one scale covers all ``page_size * h`` elements a
   (page, head) pair holds) and ``"token"`` stores
-  ``[num_pages, page_size, K]`` (per-row: one scale per (page, token
-  offset, head), covering ``h`` elements). A stored code ``c`` represents
+  ``[num_pages, K, page_size]`` (per-row: one scale per (page, head,
+  token offset), covering ``h`` elements). A stored code ``c`` represents
   ``c * scale[...]`` under its covering scale.
 - Scales are **amax-derived**: ``scale = max(|x|) / qmax`` over the covered
   elements. Under ``"head"`` granularity the scale grows monotonically on
@@ -81,10 +81,11 @@ def qmax(dtype) -> float:
 
 
 def amax_scale(rows, dtype, granularity: str = "head"):
-    """Amax scale for page rows ``[..., ps, K, h]``: ``"head"`` reduces the
-    token and head-dim axes -> ``[..., K]``; ``"token"`` reduces only the
-    head-dim axis -> ``[..., ps, K]`` (one scale per row)."""
-    axes = (-3, -1) if granularity == "head" else (-1,)
+    """Amax scale for head-major page rows ``[..., K, ps, h]``: ``"head"``
+    reduces the token and head-dim axes -> ``[..., K]``; ``"token"``
+    reduces only the head-dim axis -> ``[..., K, ps]`` (one scale per
+    row)."""
+    axes = (-2, -1) if granularity == "head" else (-1,)
     a = jnp.max(jnp.abs(rows.astype(jnp.float32)), axis=axes)
     return a / qmax(dtype)
 
@@ -105,12 +106,12 @@ def decode(codes, scale):
 
 
 def quantize_page_rows(rows, dtype, granularity: str = "head"):
-    """Quantize dense page rows ``[..., ps, K, h]`` in one shot.
+    """Quantize head-major page rows ``[..., K, ps, h]`` in one shot.
     Returns ``(codes, scales)`` with scales ``[..., K]`` (``"head"``) or
-    ``[..., ps, K]`` (``"token"``) — the layouts the pool's sibling scale
+    ``[..., K, ps]`` (``"token"``) — the layouts the pool's sibling scale
     leaves store and the paged kernels read."""
     scales = amax_scale(rows, dtype, granularity)
-    bcast = (scales[..., None, :, None] if granularity == "head"
+    bcast = (scales[..., None, None] if granularity == "head"
              else scales[..., None])
     return encode(rows, bcast, dtype), scales
 

@@ -20,13 +20,43 @@ from repro.distributed.sharding import constrain
 NEG_INF = -1e30
 
 
+def on_tpu() -> bool:
+    """Whether the process runs on the TPU backend: the Pallas kernels are
+    compiled there and interpreted anywhere else."""
+    return jax.default_backend() == "tpu"
+
+
+def pallas_on(opts: Optional["ModelOptions"]) -> bool:
+    """Whether the served attention cores (decode and chunk modes) run the
+    Pallas kernels: the platform decides (compiled kernels on the TPU, jnp
+    cores elsewhere) unless ``opts.use_pallas`` pins it."""
+    if opts is None or opts.use_pallas is None:
+        return on_tpu()
+    return opts.use_pallas
+
+
+def pallas_pinned(opts: Optional["ModelOptions"]) -> bool:
+    """Whether the kernels off the served path run: fresh-mode flash
+    attention (training, whole-buffer prefill), the MoE grouped matmul and
+    the SSD scan. Only a pin (``use_pallas=True``) selects them, never the
+    platform: flash has no backward rule and the SSD kernel's blocks are
+    refused by the TPU lowering."""
+    return opts is not None and opts.use_pallas is True
+
+
 @dataclass(frozen=True)
 class ModelOptions:
     """Runtime knobs (orthogonal to the architecture config)."""
     dense_attn_threshold: int = 2048   # use plain masked attention below this
     attn_chunk: int = 512              # q/kv chunk for banded/flash-ref paths
-    use_pallas: bool = False           # route hot ops through Pallas kernels
-    pallas_interpret: bool = True      # CPU validation mode
+    use_pallas: Optional[bool] = None  # None: served attention follows
+    #                                    the platform (see pallas_on), the
+    #                                    other kernels stay off (see
+    #                                    pallas_pinned). True runs every
+    #                                    kernel (interpreted off the TPU:
+    #                                    kernel tests); False keeps the jnp
+    #                                    cores (the reference the kernels
+    #                                    are checked against)
     moe_capacity_factor: float = 1.25
     moe_per_seq_dispatch: bool = False  # per-sequence-local slot assignment
     #                                     (no cross-device prefix sums; §Perf)
@@ -320,8 +350,9 @@ def attention_chunk_banded(q, k_cache, v_cache, index, window: int,
     ``index .. index+S-1`` attends against a live cache view, scanned over
     fixed ``band``-sized key blocks with an online softmax.
 
-    q [B,S,N,h]; cache view [B,L,K,h] (the caller slices L down to the
-    banded live bound — see ``band_len``); index scalar or per-slot [B].
+    q [B,S,N,h]; head-major cache view [B,K,L,h] (the caller slices L down
+    to the banded live bound — see ``band_len``); index scalar or per-slot
+    [B].
 
     The bit-stability contract the scheduler's equality gates build on: a
     key block that is fully masked for a query row updates that row's
@@ -332,11 +363,11 @@ def attention_chunk_banded(q, k_cache, v_cache, index, window: int,
     cache view, changes only which blocks are no-ops — never the bits.
     """
     B, S, N, h = q.shape
-    L, K = k_cache.shape[1], k_cache.shape[2]
+    K, L = k_cache.shape[1], k_cache.shape[2]
     G = N // K
     Lp = -(-L // band) * band
     if Lp != L:                      # pad the view to whole blocks; padded
-        pad = ((0, 0), (0, Lp - L), (0, 0), (0, 0))   # lanes sit past every
+        pad = ((0, 0), (0, 0), (0, Lp - L), (0, 0))   # lanes sit past every
         k_cache = jnp.pad(k_cache, pad)               # query position and
         v_cache = jnp.pad(v_cache, pad)               # are masked exactly
     nk = Lp // band
@@ -347,10 +378,10 @@ def attention_chunk_banded(q, k_cache, v_cache, index, window: int,
 
     def block(st, jk):
         m, l, acc = st
-        kj = jax.lax.dynamic_slice_in_dim(k_cache, jk * band, band, 1)
-        vj = jax.lax.dynamic_slice_in_dim(v_cache, jk * band, band, 1)
+        kj = jax.lax.dynamic_slice_in_dim(k_cache, jk * band, band, 2)
+        vj = jax.lax.dynamic_slice_in_dim(v_cache, jk * band, band, 2)
         kpos = jk * band + jnp.arange(band)
-        s = jnp.einsum("bskgh,btkh->bkgst", qg, kj).astype(jnp.float32)
+        s = jnp.einsum("bskgh,bkth->bkgst", qg, kj).astype(jnp.float32)
         mask = kpos[None, None] <= q_pos[..., None]           # [B, S, band]
         if window != GLOBAL_WINDOW:
             mask &= (q_pos[..., None] - kpos[None, None]) < window
@@ -359,7 +390,7 @@ def attention_chunk_banded(q, k_cache, v_cache, index, window: int,
         p = jnp.exp(s - m_new[..., None]) * mask[:, None, None]
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(-1)
-        pv = jnp.einsum("bkgst,btkh->bkgsh", p,
+        pv = jnp.einsum("bkgst,bkth->bkgsh", p,
                         vj.astype(jnp.float32))
         acc_new = acc * corr[..., None] + pv
         return (m_new, l_new, acc_new), None
@@ -373,25 +404,17 @@ def attention_chunk_banded(q, k_cache, v_cache, index, window: int,
         .astype(q.dtype)
 
 
-def attention_decode(q, k_cache, v_cache, index, window: int,
-                     opts: Optional[ModelOptions] = None):
-    """Single-token decode against a cache. q [B,1,N,h]; cache [B,Smax,K,h];
-    index = current position — scalar int32 or per-slot [B] vector
-    (continuous batching). With ``opts.use_pallas`` the bandwidth-tuned
-    flash-decode kernel handles both index forms; the einsum path below is
-    the oracle."""
-    if opts is not None and opts.use_pallas:
-        from repro.kernels.decode_attention import ops as da_ops
-        out = da_ops.decode_attention(q[:, 0], k_cache, v_cache, index,
-                                      window=window,
-                                      interpret=opts.pallas_interpret)
-        return out[:, None]
+def attention_decode(q, k_cache, v_cache, index, window: int):
+    """Single-token decode against a cache (the jnp core; the Pallas twin is
+    ``kernels/decode_attention``). q [B,1,N,h]; head-major cache
+    [B,K,Smax,h]; index = current position — scalar int32 or per-slot [B]
+    vector (continuous batching)."""
     B, _, N, h = q.shape
-    Smax, K = k_cache.shape[1], k_cache.shape[2]
+    K, Smax = k_cache.shape[1], k_cache.shape[2]
     G = N // K
     scale = float(1.0 / np.sqrt(h))
     qg = (q * scale).reshape(B, K, G, h)
-    s = jnp.einsum("bkgh,btkh->bkgt", qg, k_cache).astype(jnp.float32)
+    s = jnp.einsum("bkgh,bkth->bkgt", qg, k_cache).astype(jnp.float32)
     kpos = jnp.arange(Smax)
     idx = jnp.broadcast_to(jnp.asarray(index), (B,))
     valid = kpos[None] <= idx[:, None]                      # [B, Smax]
@@ -399,7 +422,7 @@ def attention_decode(q, k_cache, v_cache, index, window: int,
         valid &= (idx[:, None] - kpos[None]) < window
     s = jnp.where(valid[:, None, None], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgt,btkh->bkgh", w, v_cache)
+    out = jnp.einsum("bkgt,bkth->bkgh", w, v_cache)
     return out.reshape(B, 1, N, h)
 
 
@@ -410,42 +433,43 @@ def attention_decode_ring(q, k_cache, v_cache, index):
     slot order doesn't matter. Only slots not yet written (index < W) mask.
     """
     B, _, N, h = q.shape
-    W, K = k_cache.shape[1], k_cache.shape[2]
+    K, W = k_cache.shape[1], k_cache.shape[2]
     G = N // K
     scale = float(1.0 / np.sqrt(h))
     qg = (q * scale).reshape(B, K, G, h)
-    s = jnp.einsum("bkgh,btkh->bkgt", qg, k_cache).astype(jnp.float32)
+    s = jnp.einsum("bkgh,bkth->bkgt", qg, k_cache).astype(jnp.float32)
     slot = jnp.arange(W)
     idx = jnp.broadcast_to(jnp.asarray(index), (B,))
     valid = (slot[None] <= idx[:, None]) | (idx[:, None] >= W)
     s = jnp.where(valid[:, None, None], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgt,btkh->bkgh", w, v_cache)
+    out = jnp.einsum("bkgt,bkth->bkgh", w, v_cache)
     return out.reshape(B, 1, N, h)
 
 
 def update_cache(cache, new, index):
-    """Write `new` [B,S,K,h] into `cache` [B,Smax,K,h] at position(s) `index`
-    (scalar, or [B] per-slot vector for continuous batching)."""
+    """Write `new` [B,S,K,h] into the head-major `cache` [B,K,Smax,h] at
+    position(s) `index` (scalar, or [B] per-slot vector for continuous
+    batching)."""
     idx = jnp.asarray(index)
+    new = new.swapaxes(1, 2).astype(cache.dtype)              # [B,K,S,h]
     if idx.ndim == 0:
-        return jax.lax.dynamic_update_slice_in_dim(
-            cache, new.astype(cache.dtype), idx, 1)
+        return jax.lax.dynamic_update_slice_in_dim(cache, new, idx, 2)
     return jax.vmap(
-        lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
-            c, n.astype(c.dtype), i, 0))(cache, new, idx)
+        lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(c, n, i, 1))(
+            cache, new, idx)
 
 
 def update_cache_chunk(cache, new, index, n_valid=None):
-    """Write a prefill chunk ``new`` [B,C,K,h] into ``cache`` [B,Smax,K,h]
-    at positions ``index .. index+C-1`` (``index`` scalar or [B]) via
-    scatter rather than ``dynamic_update_slice``: rows at or past
+    """Write a prefill chunk ``new`` [B,C,K,h] into the head-major ``cache``
+    [B,K,Smax,h] at positions ``index .. index+C-1`` (``index`` scalar or
+    [B]) via scatter rather than ``dynamic_update_slice``: rows at or past
     ``n_valid`` — the padding tail of a partial final chunk — get an
     out-of-bounds target index, which jax scatter *drops* (where a slice
     update would clamp the start and shift the whole write onto earlier,
     already-correct positions)."""
     B, C = new.shape[:2]
-    smax = cache.shape[1]
+    smax = cache.shape[2]
     idx = (jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1, 1),
                             (B, 1))
            + jnp.arange(C, dtype=jnp.int32)[None])            # [B,C]
@@ -453,7 +477,9 @@ def update_cache_chunk(cache, new, index, n_valid=None):
         nv = jnp.broadcast_to(jnp.asarray(n_valid, jnp.int32).reshape(-1, 1),
                               (B, 1))
         idx = jnp.where(jnp.arange(C)[None] < nv, idx, smax)
-    return cache.at[jnp.arange(B)[:, None], idx].set(new.astype(cache.dtype))
+    # the indexed region of cache[b, :, idx] is [B, C, K, h], new's layout
+    return cache.at[jnp.arange(B)[:, None], :, idx].set(
+        new.astype(cache.dtype))
 
 
 def update_cache_paged(pages, new, page_table, index, scales=None,
@@ -462,10 +488,11 @@ def update_cache_paged(pages, new, page_table, index, scales=None,
     when the pool is quantized. Returns ``(pages, scales)`` (scales is None
     for unquantized pools).
 
-    pages [num_pages, page_size, K, h]; new [B,1,K,h]; page_table [B,npg]
-    int32; index scalar or per-slot [B] vector; scales ``[num_pages, K]``
-    (per-(page, head) granularity) or ``[num_pages, page_size, K]``
-    (per-token granularity) float32 — quantized pools only, dispatched on
+    pages [num_pages, K, page_size, h] (head-major); new [B,1,K,h];
+    page_table [B,npg] int32; index scalar or per-slot [B] vector; scales
+    ``[num_pages, K]`` (per-(page, head) granularity) or
+    ``[num_pages, K, page_size]`` (per-token granularity) float32 —
+    quantized pools only, dispatched on
     ``scales.ndim``. ``valid`` (scalar or [B] bool, default
     all-true) additionally routes masked rows to the null-page sink as
     zeros — the chunked-prefill path uses it for the padding rows of a
@@ -490,17 +517,21 @@ def update_cache_paged(pages, new, page_table, index, scales=None,
     property the speculative tick's rejected-row rollback relies on).
     Retired slots (table row all null page 0) keep the null page's
     documented all-zero state: their token codes and scale updates are
-    masked to zero, so page 0 always dequantizes to exactly 0."""
-    ps = pages.shape[1]
+    masked to zero, so page 0 always dequantizes to exactly 0.
+
+    Indexing ``pages[pid, :, off]`` (advanced indices around the head
+    slice) selects the [B, K, h] token rows, the layout of ``new[:, 0]``."""
+    ps = pages.shape[2]
     B = new.shape[0]
     idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1), (B,))
     pid = jnp.take_along_axis(page_table, (idx // ps)[:, None], axis=1)[:, 0]
+    off = idx % ps
     if valid is not None:
         live = jnp.broadcast_to(jnp.asarray(valid, bool).reshape(-1), (B,))
         pid = jnp.where(live, pid, 0)
         new = jnp.where(live[:, None, None, None], new, 0)
     if scales is None:
-        return pages.at[pid, idx % ps].set(new[:, 0].astype(pages.dtype)), None
+        return pages.at[pid, :, off].set(new[:, 0].astype(pages.dtype)), None
     from repro.models import kv_quant
     tok = new[:, 0].astype(jnp.float32)                       # [B,K,h]
     sink = (pid == 0)                                         # retired slot
@@ -509,8 +540,8 @@ def update_cache_paged(pages, new, page_table, index, scales=None,
         tok = jnp.where(sink[:, None, None], 0.0, tok)
         row_scale = jnp.max(jnp.abs(tok), -1) / kv_quant.qmax(pages.dtype)
         codes = kv_quant.encode(tok, row_scale[:, :, None], pages.dtype)
-        return (pages.at[pid, idx % ps].set(codes),
-                scales.at[pid, idx % ps].set(row_scale))
+        return (pages.at[pid, :, off].set(codes),
+                scales.at[pid, :, off].set(row_scale))
     old_scale = scales[pid]                                   # [B,K]
     tok_scale = jnp.max(jnp.abs(tok), -1) / kv_quant.qmax(pages.dtype)
     new_scale = jnp.where(sink[:, None], old_scale,
@@ -519,18 +550,18 @@ def update_cache_paged(pages, new, page_table, index, scales=None,
 
     def rescale(pages, scales):
         # some page's range grew: dequant -> insert token -> requant
-        page_f = kv_quant.decode(pages[pid], old_scale[:, None, :, None])
+        page_f = kv_quant.decode(pages[pid], old_scale[:, :, None, None])
         page_f = jax.vmap(
             lambda pg, t, r: jax.lax.dynamic_update_slice_in_dim(
-                pg, t[None], r, 0))(page_f, tok, idx % ps)    # [B,ps,K,h]
-        codes = kv_quant.encode(page_f, new_scale[:, None, :, None],
+                pg, t[:, None], r, 1))(page_f, tok, off)      # [B,K,ps,h]
+        codes = kv_quant.encode(page_f, new_scale[:, :, None, None],
                                 pages.dtype)
         return pages.at[pid].set(codes), scales.at[pid].set(new_scale)
 
     def row_only(pages, scales):
         # every scale unchanged: single-row write, no page round-trip
         codes = kv_quant.encode(tok, old_scale[:, :, None], pages.dtype)
-        return pages.at[pid, idx % ps].set(codes), scales
+        return pages.at[pid, :, off].set(codes), scales
 
     return jax.lax.cond(jnp.any(new_scale > old_scale), rescale, row_only,
                         pages, scales)
@@ -559,21 +590,23 @@ def update_cache_paged_chunk(pages, new, page_table, start, n_valid=None,
     nv = jnp.broadcast_to(
         jnp.asarray(C if n_valid is None else n_valid, jnp.int32).reshape(-1),
         (B,))
-    ps = pages.shape[1]
+    ps = pages.shape[2]
     if scales is None or scales.ndim == 3:
         idx = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]   # [B,C]
         live = jnp.arange(C)[None] < nv[:, None]                      # [B,C]
         pid = jnp.take_along_axis(page_table, idx // ps, axis=1)
         pid = jnp.where(live, pid, 0)
+        off = idx % ps
         rows = jnp.where(live[..., None, None], new, 0)
+        # pages[pid, :, off] is the [B, C, K, h] region, rows' layout
         if scales is None:
-            return pages.at[pid, idx % ps].set(rows.astype(pages.dtype)), None
+            return pages.at[pid, :, off].set(rows.astype(pages.dtype)), None
         from repro.models import kv_quant
         rows = rows.astype(jnp.float32)
         row_scale = jnp.max(jnp.abs(rows), -1) / kv_quant.qmax(pages.dtype)
         codes = kv_quant.encode(rows, row_scale[..., None], pages.dtype)
-        return (pages.at[pid, idx % ps].set(codes),
-                scales.at[pid, idx % ps].set(row_scale))
+        return (pages.at[pid, :, off].set(codes),
+                scales.at[pid, :, off].set(row_scale))
 
     def body(i, carry):
         pages, scales = carry
@@ -585,28 +618,18 @@ def update_cache_paged_chunk(pages, new, page_table, start, n_valid=None,
 
 
 def attention_decode_paged(q, k_pages, v_pages, page_table, index,
-                           window: int, opts: Optional[ModelOptions] = None,
-                           k_scales=None, v_scales=None):
-    """Single-token decode against a paged KV pool. q [B,1,N,h]; pages
-    [num_pages, page_size, K, h]; page_table [B,npg]; index scalar or [B];
-    k/v_scales [num_pages, K] or [num_pages, page_size, K] float32 for
-    quantized pools (None otherwise).
+                           window: int, k_scales=None, v_scales=None):
+    """Single-token decode against a paged KV pool (the jnp core; the
+    Pallas twin, ``kernels/decode_attention/paged.py``, gathers KV blocks
+    and their scales through the page table inside the kernel). q
+    [B,1,N,h]; pages [num_pages, K, page_size, h]; page_table [B,npg];
+    index scalar or [B]; k/v_scales [num_pages, K] or
+    [num_pages, K, page_size] float32 for quantized pools (None otherwise).
 
-    With ``opts.use_pallas`` the per-slot paged flash-decode kernel gathers
-    KV blocks (and their scales) through the page table inside the kernel
-    (scalar-prefetched index map) and dequantizes inside the VMEM tile. The
-    fallback materializes the dense gather (dequantized, for quantized
-    pools) and reuses ``attention_decode`` — bit-identical to the dense
-    layout in the unquantized case, which is what the paged-vs-dense
-    equivalence gates rely on."""
-    if opts is not None and opts.use_pallas:
-        from repro.kernels.decode_attention import ops as da_ops
-        out = da_ops.paged_decode_attention(q[:, 0], k_pages, v_pages,
-                                            page_table, index,
-                                            k_scales=k_scales,
-                                            v_scales=v_scales, window=window,
-                                            interpret=opts.pallas_interpret)
-        return out[:, None]
+    It materializes the dense gather (dequantized, for quantized pools) and
+    reuses ``attention_decode`` — bit-identical to the dense layout in the
+    unquantized case, which is what the paged-vs-dense equivalence gates
+    rely on."""
     from repro.kernels.decode_attention.ref import gather_dequant
     kd, vd = gather_dequant(k_pages, v_pages, page_table, k_scales, v_scales)
     return attention_decode(q, kd, vd, index, window)
@@ -634,29 +657,35 @@ def attention_route(mode: str, layout: str, *, S: int, Skv: int, window: int,
                     shapes — no earlier cache contents to see)
       - ``cross``   encoder context (never cached, never causal)
 
-    Layouts: ``dense`` per-slot [B, Smax, K, h] buffers; ``paged`` shared
-    page pools behind a per-slot table; ``ring`` per-layer-window ring
-    buffers; ``none`` (no cache view).
+    Layouts: ``dense`` per-slot head-major [B, K, Smax, h] buffers;
+    ``paged`` shared head-major page pools [num_pages, K, page_size, h]
+    behind a per-slot table; ``ring`` per-layer-window ring buffers;
+    ``none`` (no cache view).
+
+    Kernel or jnp core: decode and chunk modes take ``pallas_on(opts)`` —
+    the platform unless a test pins ``opts.use_pallas``; fresh mode takes
+    the flash kernel only under that pin (``pallas_pinned``).
 
     Shape gates: the fresh Pallas flash kernel keeps its
     ``S % 128 == 0 and Sq == Skv`` tiling gate, but chunk mode has no such
     restriction — the banded chunk kernel takes any (padded) chunk length
     against any cache view, which is how the old Pallas gate generalizes
     to padded bands."""
+    kernels = pallas_on(opts)
     if mode == "decode":
         if layout == "ring":
             return "decode_ring"
         if layout == "paged":
-            return ("decode_paged_flash" if opts.use_pallas
+            return ("decode_paged_flash" if kernels
                     else "decode_paged_gather")
-        return "decode_flash" if opts.use_pallas else "decode_dense"
+        return "decode_flash" if kernels else "decode_dense"
     if mode == "chunk":
         if layout == "paged":
-            return ("chunk_paged_flash" if opts.use_pallas
+            return ("chunk_paged_flash" if kernels
                     else "chunk_banded_gather")
-        return "chunk_flash" if opts.use_pallas else "chunk_banded"
+        return "chunk_flash" if kernels else "chunk_banded"
     # fresh / cross: attention over exactly the new rows
-    if opts.use_pallas and causal and S % 128 == 0 and Skv == S:
+    if pallas_pinned(opts) and causal and S % 128 == 0 and Skv == S:
         return "fresh_flash"
     if Skv <= opts.dense_attn_threshold or Skv % opts.attn_chunk \
             or not causal:
@@ -671,20 +700,21 @@ def run_attention_core(route: str, q, k, v, *, opts: ModelOptions,
                        k_pos=None, index=None, page_table=None,
                        k_scales=None, v_scales=None, live_len=None):
     """Execute one routed attention core. ``k``/``v`` are the new rows
-    (fresh/cross), the cache view [B, Smax, K, h] (dense decode/chunk), or
-    the page pools [num_pages, page_size, K, h] (paged routes, with
-    ``page_table`` and optional quantization ``*_scales``). ``index`` is
-    the decode position / chunk start (scalar or per-slot [B]);
-    ``live_len`` (static int, per-slot tuple of ints, or None) bounds the
-    banded chunk cores' key axis to the live prefix — see ``band_len`` and
-    ``live_bound``."""
+    [B, S, K, h] (fresh/cross), the head-major cache view [B, K, Smax, h]
+    (dense decode/chunk), or the page pools [num_pages, K, page_size, h]
+    (paged routes, with ``page_table`` and optional quantization
+    ``*_scales``). ``index`` is the decode position / chunk start (scalar
+    or per-slot [B]); ``live_len`` (static int, per-slot tuple of ints, or
+    None) bounds the banded chunk cores' key axis to the live prefix — see
+    ``band_len`` and ``live_bound``. Kernels run interpreted off the TPU."""
+    interpret = not on_tpu()
     # -- decode: one token against the cache --------------------------------
     if route == "decode_ring":
         return attention_decode_ring(q, k, v, index)
     if route == "decode_flash":
         from repro.kernels.decode_attention import ops as da_ops
         out = da_ops.decode_attention(q[:, 0], k, v, index, window=window,
-                                      interpret=opts.pallas_interpret)
+                                      interpret=interpret)
         return out[:, None]
     if route == "decode_dense":
         return attention_decode(q, k, v, index, window)
@@ -693,7 +723,7 @@ def run_attention_core(route: str, q, k, v, *, opts: ModelOptions,
         out = da_ops.paged_decode_attention(q[:, 0], k, v, page_table, index,
                                             k_scales=k_scales,
                                             v_scales=v_scales, window=window,
-                                            interpret=opts.pallas_interpret)
+                                            interpret=interpret)
         return out[:, None]
     if route == "decode_paged_gather":
         return attention_decode_paged(q, k, v, page_table, index, window,
@@ -701,24 +731,24 @@ def run_attention_core(route: str, q, k, v, *, opts: ModelOptions,
     # -- chunk: S > 1 prefill against a live cache view ---------------------
     band = opts.prefill_band
     if route in ("chunk_flash", "chunk_banded"):
-        smax = k.shape[1]
+        smax = k.shape[2]
         Lb = band_len(live_bound(live_len, smax), band, smax)
-        kb, vb = k[:, :Lb], v[:, :Lb]
+        kb, vb = k[:, :, :Lb], v[:, :, :Lb]
         if route == "chunk_flash":
             from repro.kernels.chunk_prefill import ops as cp_ops
             return cp_ops.chunk_prefill_attention(
                 q, kb, vb, index, window=window, bk=band,
-                interpret=opts.pallas_interpret)
+                interpret=interpret)
         return attention_chunk_banded(q, kb, vb, index, window, band)
     if route in ("chunk_paged_flash", "chunk_banded_gather"):
-        ps, npg = k.shape[1], page_table.shape[1]
+        ps, npg = k.shape[2], page_table.shape[1]
         Lb = band_len(live_bound(live_len, npg * ps), band, npg * ps)
         pt = page_table[:, :(Lb + ps - 1) // ps]
         if route == "chunk_paged_flash":
             from repro.kernels.chunk_prefill import ops as cp_ops
             return cp_ops.paged_chunk_prefill_attention(
                 q, k, v, pt, index, k_scales=k_scales, v_scales=v_scales,
-                window=window, interpret=opts.pallas_interpret)
+                window=window, interpret=interpret)
         from repro.kernels.decode_attention.ref import gather_dequant
         kd, vd = gather_dequant(k, v, pt, k_scales, v_scales)
         return attention_chunk_banded(q, kd, vd, index, window, band)
@@ -730,7 +760,7 @@ def run_attention_core(route: str, q, k, v, *, opts: ModelOptions,
         if route == "fresh_flash":
             from repro.kernels.flash_attention import ops as fa_ops
             return fa_ops.flash_attention(q, k, v, window=window,
-                                          interpret=opts.pallas_interpret)
+                                          interpret=interpret)
         if route == "fresh_dense":
             return attention_dense(q, k, v, q_pos, k_pos, window, causal)
         if route == "fresh_banded":
@@ -750,10 +780,11 @@ def attention(p, x, cfg: ModelConfig, opts: ModelOptions, window: int,
     routed core (``attention_route`` / ``run_attention_core``) + output
     projection.
 
-    Decode mode when ``cache`` is a (k,v) tuple and x has S==1.
+    Decode mode when ``cache`` is a (k,v) tuple of head-major
+    [B, K, Smax, h] buffers and x has S==1.
     Cross-attention when ``ctx`` (encoder output) is given: K/V from ctx.
     With ``page_table`` [B,npg] the cache tuple is interpreted as paged
-    pools [num_pages, page_size, K, h]; S>1 runs a prefill chunk that is
+    pools [num_pages, K, page_size, h]; S>1 runs a prefill chunk that is
     scattered page-wise and attends through the pool.
     Prefill with a cache supports ``cache_index > 0`` (chunked prefill /
     prefill-from-position): the chunk is written at its positions and its
@@ -813,14 +844,14 @@ def attention(p, x, cfg: ModelConfig, opts: ModelOptions, window: int,
             if k_sc is not None:
                 new_cache += (k_sc, v_sc)
             route = attention_route("decode" if S == 1 else "chunk", "paged",
-                                    S=S, Skv=k_cache.shape[1], window=window,
+                                    S=S, Skv=k_cache.shape[2], window=window,
                                     opts=opts, causal=causal)
             out = run_attention_core(route, q, k_cache, v_cache, opts=opts,
                                      window=window, index=cache_index,
                                      page_table=page_table, k_scales=k_sc,
                                      v_scales=v_sc, live_len=live_len)
         else:
-            smax = cache[0].shape[1]
+            smax = cache[0].shape[2]
             ring = (window != GLOBAL_WINDOW and smax == window)
             if not ring and S > smax:
                 raise ValueError(f"prefill length {S} exceeds cache {smax}")
@@ -974,10 +1005,10 @@ def moe(p, x, cfg: ModelConfig, opts: ModelOptions):
 
     xe = xt[buf_tokens].reshape(E, C, D) * buf_valid.reshape(E, C, 1)
     xe = constrain(xe, "act_experts", "batch", None)
-    if opts.use_pallas:
+    if pallas_pinned(opts):
         from repro.kernels.moe_gmm import ops as gmm_ops
         he = gmm_ops.grouped_mlp(xe, p["moe_wi"], p["moe_wg"], p["moe_wo"],
-                                 cfg.act, interpret=opts.pallas_interpret)
+                                 cfg.act, interpret=not on_tpu())
     else:
         h = jnp.einsum("ecd,edf->ecf", xe, p["moe_wi"])
         g = jnp.einsum("ecd,edf->ecf", xe, p["moe_wg"])
@@ -1150,10 +1181,10 @@ def mamba_block(p, x, cfg: ModelConfig, opts: ModelOptions,
         y = jnp.einsum("bhpn,bn->bhp", h, C_[:, 0, 0])[:, None]
         new_state = h
     else:
-        if opts.use_pallas:
+        if pallas_pinned(opts):
             from repro.kernels.ssd import ops as ssd_ops
             y, new_state = ssd_ops.ssd(xs, dt, p["A_log"], B_, C_,
-                                       interpret=opts.pallas_interpret)
+                                       interpret=not on_tpu())
         else:
             y, new_state = ssd_chunked(xs, dt, p["A_log"], B_, C_)
     y = y.astype(x.dtype) + xs.astype(x.dtype) * p["d_skip"].astype(x.dtype)[None, None, :, None]

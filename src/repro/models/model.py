@@ -262,7 +262,7 @@ def decode_step(cfg: ModelConfig, opts: ModelOptions, params, token,
     """One autoregressive step. token [B,1] int32; index: scalar position or
     per-slot [B] vector (continuous batching). ``page_table`` [B,npg]
     selects the paged cache layout: attention cache leaves are shared
-    ``[num_pages, page_size, K, h]`` pools and positions resolve through the
+    ``[num_pages, K, page_size, h]`` pools and positions resolve through the
     table (see serving.kv_pool); dense per-slot caches when None. A
     quantized pool (caches built with ``init_caches(kv_dtype="int8"/"fp8")``)
     needs no extra arguments — the int8/fp8 value leaves and their

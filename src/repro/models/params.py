@@ -7,6 +7,7 @@ lowering) and ``param_shardings`` (NamedShardings via the logical-axis rules).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -56,11 +57,22 @@ def _init_leaf(spec: PSpec, key, dtype):
     return (jax.random.normal(key, spec.shape, jnp.float32) * scale).astype(dtype)
 
 
-def init_params(template, key, dtype=jnp.float32):
+def _init_tree(template, key, dtype):
     leaves, treedef = jax.tree.flatten(template, is_leaf=is_pspec)
     keys = jax.random.split(key, len(leaves))
     return jax.tree.unflatten(
         treedef, [_init_leaf(l, k, dtype) for l, k in zip(leaves, keys)])
+
+
+def init_params(template, key, dtype=jnp.float32, shardings=None):
+    """Random weights for ``template``, drawn by one jitted program: each
+    leaf's float32 draw fuses into its cast to ``dtype``, so the device
+    never holds a float32 copy of a bf16 leaf. ``shardings`` (a tree of
+    ``Sharding`` matching ``template``, or None for the default device)
+    are the output shardings: under a mesh every device makes only its own
+    shard, and no whole tree lands on one device first."""
+    init = functools.partial(_init_tree, template, dtype=dtype)
+    return jax.jit(init, out_shardings=shardings)(key)
 
 
 def param_shapes(template, dtype=jnp.bfloat16):

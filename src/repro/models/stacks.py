@@ -403,19 +403,26 @@ def cache_template(cfg: ModelConfig, batch: int, max_seq: int,
                    scale_granularity: str = "head"):
     """Shape tree (PSpec) for the decode cache; concrete zeros via init_caches.
 
-    Dense (default): attention K/V leaves are per-slot ``[batch, seq, K, h]``
-    buffers over-allocated at ``max_seq``. Paged: attention K/V leaves become
-    shared pools ``[num_pages, page_size, K, h]`` addressed through a
-    per-slot page table (see serving.kv_pool); only attention k/v move to
-    the pool — SSM/conv state and cross-attention K/V keep the slot-batched
-    layout (they are O(1) or prompt-sized per slot, not decode-growing).
+    Dense (default): attention K/V leaves are per-slot head-major
+    ``[batch, K, seq, h]`` buffers over-allocated at ``max_seq``. Paged:
+    attention K/V leaves become shared head-major pools
+    ``[num_pages, K, page_size, h]`` addressed through a per-slot page table
+    (see serving.kv_pool); only attention k/v move to the pool — SSM/conv
+    state and cross-attention K/V keep the slot-batched layout (they are
+    O(1) or prompt-sized per slot, not decode-growing).
+
+    Head-major: a Pallas kernel's block must tile the array's last two
+    dims by (8, 128) or span them whole, so one head's positions × head_dim
+    — ``(rows, h)`` — is the block the TPU lowering accepts; with heads on
+    the second-minor axis one head's rows would be a (1, h) block it
+    refuses.
 
     ``kv_dtype`` (paged only) selects the pool storage: ``"bf16"`` keeps
     ``dtype``; ``"int8"``/``"fp8"`` store 1-byte codes and every K/V pool
     leaf gets a sibling float32 scale leaf (``k_scale``/``v_scale``) whose
     shape follows ``scale_granularity``: ``"head"`` -> ``[num_pages, K]``
     (per-page-per-head, the compact default), ``"token"`` ->
-    ``[num_pages, page_size, K]`` (per-row — rewrite-stable, required by
+    ``[num_pages, K, page_size]`` (per-row — rewrite-stable, required by
     speculative decode; see models.kv_quant)."""
     period, nblocks, ntail = stack_plan(cfg)
     kinds = sub_kinds(cfg)
@@ -439,18 +446,18 @@ def cache_template(cfg: ModelConfig, batch: int, max_seq: int,
         c: Dict[str, PSpec] = {}
         if kind.mixer == "attn":
             if paged:
-                c["k"] = PSpec((num_pages, page_size, cfg.num_kv_heads,
+                c["k"] = PSpec((num_pages, cfg.num_kv_heads, page_size,
                                 cfg.head_dim),
-                               (None, None, "act_kv_heads", None))
-                c["v"] = PSpec((num_pages, page_size, cfg.num_kv_heads,
+                               (None, "act_kv_heads", None, None))
+                c["v"] = PSpec((num_pages, cfg.num_kv_heads, page_size,
                                 cfg.head_dim),
-                               (None, None, "act_kv_heads", None))
+                               (None, "act_kv_heads", None, None))
                 if quantized:
                     sshape, sspec = ((num_pages, cfg.num_kv_heads),
                                      (None, "act_kv_heads"))
                     if scale_granularity == "token":
-                        sshape = (num_pages, page_size, cfg.num_kv_heads)
-                        sspec = (None, None, "act_kv_heads")
+                        sshape = (num_pages, cfg.num_kv_heads, page_size)
+                        sspec = (None, "act_kv_heads", None)
                     c["k_scale"] = PSpec(sshape, sspec)
                     c["v_scale"] = PSpec(sshape, sspec)
                 if kind.cross and cfg.encoder:
@@ -464,10 +471,10 @@ def cache_template(cfg: ModelConfig, batch: int, max_seq: int,
             seq = max_seq
             if opts.window_cache and kind.window != GLOBAL_WINDOW:
                 seq = min(max_seq, kind.window)
-            c["k"] = PSpec((batch, seq, cfg.num_kv_heads, cfg.head_dim),
-                           ("batch", "kv_seq", "act_kv_heads", None))
-            c["v"] = PSpec((batch, seq, cfg.num_kv_heads, cfg.head_dim),
-                           ("batch", "kv_seq", "act_kv_heads", None))
+            c["k"] = PSpec((batch, cfg.num_kv_heads, seq, cfg.head_dim),
+                           ("batch", "act_kv_heads", "kv_seq", None))
+            c["v"] = PSpec((batch, cfg.num_kv_heads, seq, cfg.head_dim),
+                           ("batch", "act_kv_heads", "kv_seq", None))
             if kind.cross and cfg.encoder:
                 c["xk"] = PSpec((batch, cfg.encoder.num_tokens,
                                  cfg.num_kv_heads, cfg.head_dim),
@@ -542,6 +549,6 @@ def is_paged_leaf(path) -> bool:
 def is_scale_leaf(path) -> bool:
     """Whether a cache-pytree leaf is a quantization scale sibling of a
     paged K/V pool leaf (``[num_pages, K]`` float32 at ``"head"``
-    granularity, ``[num_pages, page_size, K]`` at ``"token"``)."""
+    granularity, ``[num_pages, K, page_size]`` at ``"token"``)."""
     key = getattr(path[-1], "key", path[-1])
     return key in ("k_scale", "v_scale")

@@ -34,11 +34,12 @@ Two decode paths:
 
 Two cache layouts (``paged=``):
 
-- **dense** (default, and the equivalence oracle): per-slot
-  ``[n_slots, max_seq, K, h]`` buffers, over-allocated at ``max_seq``;
+- **dense** (default, and the equivalence oracle): per-slot head-major
+  ``[n_slots, K, max_seq, h]`` buffers, over-allocated at ``max_seq``;
   admission scatters the batch-1 prefill cache into the slot's batch row.
-- **paged**: attention K/V lives in shared ``[num_pages, page_size, K, h]``
-  pools addressed through a per-slot page table (``serving.kv_pool``).
+- **paged**: attention K/V lives in shared head-major
+  ``[num_pages, K, page_size, h]`` pools addressed through a per-slot page
+  table (``serving.kv_pool``).
   Admission allocates pages (sharing full prompt-prefix pages with the
   pool's prefix cache — repeated robot observations are not re-stored) and
   scatters prefill KV page-wise; finish frees pages back to the pool. Cache
@@ -84,14 +85,14 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import serving_rules, spec_for
 from repro.models import kv_quant
 from repro.models import model as M
-from repro.models.layers import ModelOptions, band_len
+from repro.models.layers import ModelOptions, band_len, on_tpu, pallas_on
 from repro.models.params import is_pspec
 from repro.models.stacks import (cache_batch_axis, cache_template,
                                  is_paged_leaf, is_scale_leaf, stack_plan)
@@ -510,9 +511,10 @@ def _jit_decode(cfg: ModelConfig, opts: ModelOptions):
 
 
 @functools.lru_cache(maxsize=None)
-def _jit_prefill(cfg: ModelConfig, opts: ModelOptions, max_seq: int):
+def _jit_prefill(cfg: ModelConfig, opts: ModelOptions, max_seq: int,
+                 cache_dtype):
     return jax.jit(lambda p, b: M.prefill(cfg, opts, p, b, max_seq,
-                                          cache_dtype=jnp.float32))
+                                          cache_dtype=cache_dtype))
 
 
 @functools.lru_cache(maxsize=None)
@@ -584,6 +586,11 @@ class ServingEngine:
                  slo_hz: float = 0.0, mesh: Optional[Mesh] = None):
         if tick_tokens < 1:
             raise ValueError(f"tick_tokens must be >= 1, got {tick_tokens}")
+        if on_tpu() and not pallas_on(opts):
+            raise ValueError("on the TPU the served path runs the compiled "
+                             "Pallas kernels: ModelOptions(use_pallas=False) "
+                             "selects the jnp reference cores, which are "
+                             "not served")
         if mesh is not None:
             # sharded serving: every device stage becomes one shard_map-ped
             # program over the 'model' axis (see _init_mesh_stages). The
@@ -636,13 +643,13 @@ class ServingEngine:
                 raise ValueError(f"chunk_size {chunk_size} must divide by "
                                  f"page_size {page_size} so chunk writes "
                                  f"start page-aligned")
-            if paged and opts.use_pallas and page_size != opts.prefill_band:
+            if paged and pallas_on(opts) and page_size != opts.prefill_band:
                 # the paged chunk kernel partitions the key axis per page
                 # while the dense kernel (monolithic prefill) partitions per
                 # prefill_band; the chunked==monolithic bit-equality
                 # contract needs one absolute partition on the kernel path
                 raise ValueError(
-                    f"chunked_prefill with paged=True and use_pallas "
+                    f"chunked_prefill with paged=True on the kernel path "
                     f"requires page_size ({page_size}) == "
                     f"ModelOptions.prefill_band ({opts.prefill_band}): the "
                     f"paged chunk-prefill kernel blocks the key axis per "
@@ -673,9 +680,9 @@ class ServingEngine:
                 raise ValueError("spec_decode requires attention-only "
                                  "decoders (SSM state cannot roll back "
                                  "rejected drafts by position re-write)")
-            if paged and opts.use_pallas and page_size != opts.prefill_band:
+            if paged and pallas_on(opts) and page_size != opts.prefill_band:
                 raise ValueError(
-                    f"spec_decode with paged=True and use_pallas requires "
+                    f"spec_decode with paged=True on the kernel path requires "
                     f"page_size ({page_size}) == ModelOptions.prefill_band "
                     f"({opts.prefill_band}): the verify pass runs the paged "
                     f"chunk-prefill kernel, whose key-axis partition must "
@@ -718,6 +725,9 @@ class ServingEngine:
                     "(see docs/speculative.md)")
         self.scale_granularity = scale_granularity    # None when unquantized
         self.cfg, self.opts, self.params = cfg, opts, params
+        # caches follow the params' dtype: bf16 weights serve a bf16 cache,
+        # float32 weights (the CPU equivalence tests) a float32 one
+        self.cache_dtype = jnp.dtype(params["embed"].dtype)
         self.mesh = mesh
         self._c1specs = None               # set by _init_mesh_stages
         self.n_slots, self.max_seq, self.eos = n_slots, max_seq, eos
@@ -743,8 +753,9 @@ class ServingEngine:
                 # worst case every slot fills up, +1 for the null page
                 num_pages = 1 + n_slots * pages_per_slot
             self.pool = KVPool(num_pages, page_size, n_slots, pages_per_slot)
-            self.caches = M.init_caches(cfg, n_slots, max_seq, jnp.float32,
-                                        opts, paged=True, num_pages=num_pages,
+            self.caches = M.init_caches(cfg, n_slots, max_seq,
+                                        self.cache_dtype, opts, paged=True,
+                                        num_pages=num_pages,
                                         page_size=page_size,
                                         kv_dtype=kv_dtype,
                                         scale_granularity=(scale_granularity
@@ -754,8 +765,8 @@ class ServingEngine:
                 jax.tree_util.tree_leaves_with_path(self.caches)
                 if is_paged_leaf(path))
         else:
-            self.caches = M.init_caches(cfg, n_slots, max_seq, jnp.float32,
-                                        opts)
+            self.caches = M.init_caches(cfg, n_slots, max_seq,
+                                        self.cache_dtype, opts)
             self._bytes_per_page = 0
         # per-device bytes per page: equals the summed figure on one device,
         # recomputed from actual shard buffers under a mesh
@@ -781,7 +792,7 @@ class ServingEngine:
                                       max(0, self.pool.num_pages - 2)))
 
         self._decode = _jit_decode(cfg, opts)
-        self._prefill = _jit_prefill(cfg, opts, max_seq)
+        self._prefill = _jit_prefill(cfg, opts, max_seq, self.cache_dtype)
         self._vision = (_jit_vision(cfg, opts)
                         if cfg.vision is not None else None)
         self._tick = _jit_tick(cfg, opts, tick_tokens, eos, temperature,
@@ -815,7 +826,7 @@ class ServingEngine:
         Layout (Megatron-style tensor parallelism, serving_rules):
 
         - attention heads and KV-cache pages shard on the head axis: each
-          device owns ``[num_pages, page_size, K/n, h]`` slices of every
+          device owns ``[num_pages, K/n, page_size, h]`` slices of every
           page, so the paged kernels run *unchanged* per shard and the
           host-side page tables stay global (replicated operands). GQA
           divisibility is atomic — smollm's 9/3 heads replicate over
@@ -829,39 +840,25 @@ class ServingEngine:
           state, budgets) is replicated, so scheduler/kv_pool code observes
           no mesh at all.
 
-        ``check_rep=False`` everywhere: jax 0.4.x has no replication rule
-        for ``lax.while_loop``, which both fused ticks are built on."""
+        ``check_vma=False`` everywhere: the sharded-vs-single-device stream
+        tests, not the tracer, check that outputs declared replicated are
+        replicated."""
         cfg, opts = self.cfg, self.opts
-        rules = serving_rules(mesh.shape["model"], cfg.num_heads,
-                              cfg.num_kv_heads)
-        self._serving_rule_table = rules
+        self._serving_rule_table, specs_of = _serving_specs(cfg, mesh)
         shopts = dataclasses.replace(opts, shard_axis="model")
-
-        def specs_of(template):
-            return jax.tree_util.tree_map(
-                lambda s: spec_for(s.shape, s.axes, mesh, rules),
-                template, is_leaf=is_pspec)
-
-        templ = M.model_template(cfg)
-        pspecs = specs_of(templ)
-        # towers (vision / action head) run as plain einsum stacks with no
-        # collective insertion — their params must stay whole per shard
-        for k in ("vision", "encoder", "action_dit"):
-            if k in pspecs:
-                pspecs[k] = jax.tree_util.tree_map(
-                    lambda s: P(), templ[k], is_leaf=is_pspec)
+        pspecs = serving_param_specs(cfg, mesh)
         if self.paged:
             cspecs = specs_of(cache_template(
-                cfg, self.n_slots, self.max_seq, jnp.float32, opts,
+                cfg, self.n_slots, self.max_seq, self.cache_dtype, opts,
                 paged=True, num_pages=self.pool.num_pages,
                 page_size=self.page_size, kv_dtype=self.kv_dtype,
                 scale_granularity=(self.scale_granularity or "head")))
         else:
             cspecs = specs_of(cache_template(cfg, self.n_slots, self.max_seq,
-                                             jnp.float32, opts))
+                                             self.cache_dtype, opts))
         # batch-1 dense cache (prefill output / chunked-prefill carry)
-        c1specs = specs_of(cache_template(cfg, 1, self.max_seq, jnp.float32,
-                                          opts))
+        c1specs = specs_of(cache_template(cfg, 1, self.max_seq,
+                                          self.cache_dtype, opts))
         self._c1specs = c1specs
 
         def place(tree, specs):
@@ -889,7 +886,7 @@ class ServingEngine:
 
         def smap(f, in_specs, out_specs):
             return shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
+                             out_specs=out_specs, check_vma=False)
 
         self._decode = jax.jit(smap(
             lambda p, t, c, i, pt: M.decode_step(cfg, shopts, p, t, c, i,
@@ -899,10 +896,11 @@ class ServingEngine:
         # its own caches inside the shard trace (init_caches would build the
         # global head count), so a reusable sharded zero tree rides along
         self._cache1_zeros = place(
-            M.init_caches(cfg, 1, self.max_seq, jnp.float32, opts), c1specs)
+            M.init_caches(cfg, 1, self.max_seq, self.cache_dtype, opts),
+            c1specs)
         prefill_sharded = jax.jit(smap(
             lambda p, b, c0: M.prefill(cfg, shopts, p, b, self.max_seq,
-                                       cache_dtype=jnp.float32,
+                                       cache_dtype=self.cache_dtype,
                                        fresh_caches=c0),
             (pspecs, R, c1specs), (R, c1specs)))
         self._prefill = lambda p, b: prefill_sharded(p, b,
@@ -960,12 +958,28 @@ class ServingEngine:
                 smap(_reset_page_scales_impl, (cspecs, R), cspecs),
                 donate_argnums=0)
 
+    def stage_programs(self, opts: Optional[ModelOptions] = None):
+        """The engine's vision, chunked-prefill and single-step decode
+        programs, as ``(vision, prefill_chunk, decode)``. With ``opts``
+        (single-device engines only) the prefill and decode programs are
+        traced under those options instead — e.g. ``use_pallas=False``,
+        the jnp reference cores, for checking the kernels against."""
+        if opts is None or opts == self.opts:
+            return (self._vision, getattr(self, "_prefill_chunk", None),
+                    self._decode)
+        if self.mesh is not None:
+            raise ValueError("stage_programs(opts) re-traces single-device "
+                             "programs; this engine is sharded")
+        return (self._vision, _jit_prefill_chunk(self.cfg, opts, self.paged),
+                _jit_decode(self.cfg, opts))
+
     def _fresh_cache1(self):
         """Zeroed batch-1 dense cache for one chunked-prefill admission.
         Dense chunks donate their cache carry, so each admission needs its
         own tree (the monolithic path's zeros are reusable — prefill there
         is non-donating)."""
-        c = M.init_caches(self.cfg, 1, self.max_seq, jnp.float32, self.opts)
+        c = M.init_caches(self.cfg, 1, self.max_seq, self.cache_dtype,
+                          self.opts)
         if self.mesh is not None:
             c = jax.tree_util.tree_map(
                 lambda x, sp: jax.device_put(
@@ -1700,7 +1714,7 @@ class ServingEngine:
                         # run itself is skipped (chunks never read these
                         # embedding rows)
                         prefix = jnp.zeros((1, n_prefix, self.cfg.d_model),
-                                           jnp.float32)
+                                           self.cache_dtype)
                     batch["prefix"] = prefix
                 embeds = M.embed_prompt(self.cfg, self.opts, self.params,
                                         batch)
@@ -1927,6 +1941,35 @@ class ServingEngine:
         return self.finished
 
 
+def _serving_specs(cfg: ModelConfig, mesh: Mesh):
+    """The ``serving_rules`` table of a ``model=N`` serving engine, and a
+    function mapping a param or cache template to its PartitionSpec tree
+    under those rules."""
+    rules = serving_rules(mesh.shape["model"], cfg.num_heads,
+                          cfg.num_kv_heads)
+
+    def specs_of(template):
+        return jax.tree_util.tree_map(
+            lambda s: spec_for(s.shape, s.axes, mesh, rules), template,
+            is_leaf=is_pspec)
+    return rules, specs_of
+
+
+def serving_param_specs(cfg: ModelConfig, mesh: Mesh):
+    """PartitionSpec tree of a ``model=N`` serving engine's params: the
+    ``serving_rules`` layout, with the towers (vision / encoder / action
+    head) whole on every shard — they run as plain einsum stacks with no
+    collective insertion. ``init_params`` takes these as its output
+    shardings, so each device only ever holds its own shard."""
+    templ = M.model_template(cfg)
+    pspecs = _serving_specs(cfg, mesh)[1](templ)
+    for k in ("vision", "encoder", "action_dit"):
+        if k in pspecs:
+            pspecs[k] = jax.tree_util.tree_map(lambda s: P(), templ[k],
+                                               is_leaf=is_pspec)
+    return pspecs
+
+
 def _path_keys(path):
     """Pytree path -> hashable tuple of dict keys (for cross-tree lookups:
     a quantized paged cache has scale leaves the dense prefill cache lacks,
@@ -1981,14 +2024,14 @@ def _scatter_pages_impl(caches, cache1, dest_pages, page_size: int):
              in jax.tree_util.tree_leaves_with_path(cache1)}
 
     def page_rows(keys, stacked):
-        """Dense prefill leaf -> page-major rows [(nb,) P, ps, K, h]."""
+        """Dense prefill leaf -> page-major rows [(nb,) P, K, ps, h]."""
         small = flat1[keys]
-        if stacked:                   # blocks: [nb, 1, S, K, h]
-            nb, _, seq = small.shape[:3]
-            return small.reshape(nb, seq // page_size, page_size,
-                                 *small.shape[3:])
-        seq = small.shape[1]          # tail: [1, S, K, h]
-        return small.reshape(seq // page_size, page_size, *small.shape[2:])
+        if not stacked:               # tail: [1, K, S, h]
+            small = small[None]
+        nb, _, K, seq, h = small.shape    # blocks: [nb, 1, K, S, h]
+        rows = small.reshape(nb, K, seq // page_size, page_size, h)
+        rows = rows.swapaxes(1, 2)
+        return rows if stacked else rows[0]
 
     out = []
     for path, big in flat_big:
@@ -2054,7 +2097,7 @@ def _copy_pages_impl(caches, src_pages, dst_pages):
     def copy(path, big):
         if not is_paged_leaf(path):
             return big
-        if cache_batch_axis(path) == 1:   # blocks: [nb, P, ps, K, h]
+        if cache_batch_axis(path) == 1:   # blocks: [nb, P, K, ps, h]
             return big.at[:, dst_pages].set(big[:, src_pages])
         return big.at[dst_pages].set(big[src_pages])
     return jax.tree_util.tree_map_with_path(copy, caches)

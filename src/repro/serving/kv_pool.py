@@ -1,7 +1,7 @@
 """Paged KV-cache block allocator (host-side control plane).
 
 The device holds one KV pool per attention layer, laid out
-``[num_pages, page_size, K, h]`` (see ``stacks.cache_template(paged=True)``).
+``[num_pages, K, page_size, h]`` (see ``stacks.cache_template(paged=True)``).
 This module owns the *metadata*: which physical pages belong to which slot,
 page refcounts, the free list, and the prefix cache. All decisions are made
 on the host between engine ticks; the device only ever sees the resulting

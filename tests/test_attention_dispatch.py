@@ -36,6 +36,25 @@ def test_route_cache_modes(mode, layout, pallas, expect):
     assert route == expect
 
 
+@pytest.mark.parametrize("tpu", [False, True])
+def test_route_platform_default(monkeypatch, tpu):
+    """Unpinned options: the served modes (decode, chunk) follow the
+    platform; fresh flash, the MoE grouped matmul and the SSD scan stay on
+    jnp even on the TPU (no backward rule, a refused SSD block)."""
+    monkeypatch.setattr(L, "on_tpu", lambda: tpu)
+    opts = ModelOptions()
+    route = lambda mode, layout, S: L.attention_route(
+        mode, layout, S=S, Skv=S if mode == "fresh" else 256,
+        window=GLOBAL_WINDOW, opts=opts)
+    assert route("decode", "paged", 1) == ("decode_paged_flash" if tpu
+                                           else "decode_paged_gather")
+    assert route("chunk", "paged", 64) == ("chunk_paged_flash" if tpu
+                                           else "chunk_banded_gather")
+    assert route("fresh", "none", 256) == "fresh_dense"
+    assert L.pallas_on(opts) is tpu
+    assert not L.pallas_pinned(opts)
+
+
 def test_route_fresh_shape_gates():
     """Fresh mode keeps the flash kernel's S % 128 == 0 / self-attention
     tiling gate; chunk mode has no such gate (the generalization to padded
@@ -86,8 +105,8 @@ def test_run_core_rejects_unknown_route():
 def _qkv(B, S, N, K, h, L_):
     ks = jax.random.split(KEY, 3)
     return (jax.random.normal(ks[0], (B, S, N, h)),
-            jax.random.normal(ks[1], (B, L_, K, h)),
-            jax.random.normal(ks[2], (B, L_, K, h)))
+            jax.random.normal(ks[1], (B, K, L_, h)),
+            jax.random.normal(ks[2], (B, K, L_, h)))
 
 
 def test_banded_chunk_matches_dense_softmax_oracle():
@@ -109,7 +128,7 @@ def test_banded_chunk_view_length_invariance():
     idx = jnp.asarray(20, jnp.int32)              # live prefix = 26
     ref = L.attention_chunk_banded(q, kc, vc, idx, GLOBAL_WINDOW, 16)
     for view in (32, 48, 96):                     # all cover live=26
-        out = L.attention_chunk_banded(q, kc[:, :view], vc[:, :view], idx,
+        out = L.attention_chunk_banded(q, kc[:, :, :view], vc[:, :, :view], idx,
                                        GLOBAL_WINDOW, 16)
         assert jnp.array_equal(out, ref), f"view {view} changed the bits"
 
@@ -178,8 +197,8 @@ def test_attention_live_len_bound_is_bitwise_noop():
     opts = ModelOptions(remat=False)
     B, S, smax, start = 1, 8, 64, 10
     x = jax.random.normal(KEY, (B, S, D))
-    cache = (jax.random.normal(KEY, (B, smax, K, h)),
-             jax.random.normal(KEY, (B, smax, K, h)))
+    cache = (jax.random.normal(KEY, (B, K, smax, h)),
+             jax.random.normal(KEY, (B, K, smax, h)))
     positions = jnp.broadcast_to(start + jnp.arange(S), (B, S))
     outs = []
     for live in (start + S, 48, None):
@@ -201,12 +220,11 @@ def test_attention_chunk_kernel_matches_banded_fallback():
     D, N, K, h = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     p = _layer_params(D, N, K, h, KEY)
     ref_opts = ModelOptions(remat=False)
-    ker_opts = ModelOptions(remat=False, use_pallas=True,
-                            pallas_interpret=True)
+    ker_opts = ModelOptions(remat=False, use_pallas=True)
     B, S, smax, start, ps = 1, 8, 64, 10, 8
     x = jax.random.normal(KEY, (B, S, D))
-    cache = (jax.random.normal(KEY, (B, smax, K, h)),
-             jax.random.normal(KEY, (B, smax, K, h)))
+    cache = (jax.random.normal(KEY, (B, K, smax, h)),
+             jax.random.normal(KEY, (B, K, smax, h)))
     positions = jnp.broadcast_to(start + jnp.arange(S), (B, S))
     o_ref, _ = L.attention(p, x, cfg, ref_opts, GLOBAL_WINDOW, positions,
                            cache=cache, cache_index=jnp.asarray(start))
@@ -216,7 +234,7 @@ def test_attention_chunk_kernel_matches_banded_fallback():
                                atol=2e-5, rtol=2e-5)
     # paged: identity page table over the same contiguous rows
     npg = smax // ps
-    pages = (cache[0].reshape(npg, ps, K, h), cache[1].reshape(npg, ps, K, h))
+    pages = tuple(c[0].reshape(K, npg, ps, h).swapaxes(0, 1) for c in cache)
     pt = jnp.arange(npg, dtype=jnp.int32)[None]
     o_pref, _ = L.attention(p, x, cfg, ref_opts, GLOBAL_WINDOW, positions,
                             cache=pages, cache_index=jnp.asarray(start),

@@ -53,8 +53,8 @@ def test_flash_attention(B, S, N, K, h, window, dtype):
 def test_decode_attention(B, S, N, K, h, idx, window, dtype):
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, N, h), dtype)
-    kc = jax.random.normal(ks[1], (B, S, K, h), dtype)
-    vc = jax.random.normal(ks[2], (B, S, K, h), dtype)
+    kc = jax.random.normal(ks[1], (B, K, S, h), dtype)
+    vc = jax.random.normal(ks[2], (B, K, S, h), dtype)
     out = decode_attention(q, kc, vc, idx, window=window, bk=256,
                            interpret=True)
     exp = dref.decode_attention_ref(q, kc, vc, idx, window=window)
@@ -71,8 +71,8 @@ def test_decode_attention_per_slot_index(B, S, N, K, h, bk, window):
     and early-exits against its own position."""
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, N, h))
-    kc = jax.random.normal(ks[1], (B, S, K, h))
-    vc = jax.random.normal(ks[2], (B, S, K, h))
+    kc = jax.random.normal(ks[1], (B, K, S, h))
+    vc = jax.random.normal(ks[2], (B, K, S, h))
     idx = jax.random.randint(ks[3], (B,), 0, S, jnp.int32)
     out = decode_attention(q, kc, vc, idx, window=window, bk=bk,
                            interpret=True)
@@ -93,8 +93,8 @@ def test_decode_attention_non_block_aligned(S, bk, idx, window):
     B, N, K, h = 2, 4, 2, 32
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, N, h))
-    kc = jax.random.normal(ks[1], (B, S, K, h))
-    vc = jax.random.normal(ks[2], (B, S, K, h))
+    kc = jax.random.normal(ks[1], (B, K, S, h))
+    vc = jax.random.normal(ks[2], (B, K, S, h))
     out = decode_attention(q, kc, vc, idx, window=window, bk=bk,
                            interpret=True)
     exp = dref.decode_attention_ref(q, kc, vc, idx, window=window)
@@ -114,8 +114,8 @@ def test_paged_decode_attention(B, npg, ps, N, K, h, window, dtype):
     P = B * npg + 3
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, N, h), dtype)
-    kp = jax.random.normal(ks[1], (P, ps, K, h), dtype)
-    vp = jax.random.normal(ks[2], (P, ps, K, h), dtype)
+    kp = jax.random.normal(ks[1], (P, K, ps, h), dtype)
+    vp = jax.random.normal(ks[2], (P, K, ps, h), dtype)
     # distinct physical pages, never the null page 0, scrambled order
     perm = jax.random.permutation(ks[3], jnp.arange(1, P))[:B * npg]
     pt = perm.reshape(B, npg).astype(jnp.int32)
@@ -128,31 +128,51 @@ def test_paged_decode_attention(B, npg, ps, N, K, h, window, dtype):
 
 
 def test_layers_decode_routes_through_kernels():
-    """layers.attention_decode / attention_decode_paged with use_pallas route
-    through the flash-decode kernels and match their einsum fallbacks."""
-    from repro.models.layers import (ModelOptions, attention_decode,
-                                     attention_decode_paged)
-    opts = ModelOptions(use_pallas=True, pallas_interpret=True)
+    """A test that pins ``use_pallas=True`` routes decode through the
+    flash-decode kernels (interpreted off the TPU), and they match the jnp
+    cores the platform picks on the CPU."""
+    from repro.models.layers import (ModelOptions, attention_route,
+                                     run_attention_core)
+    opts = ModelOptions(use_pallas=True)
     B, S, N, K, h, ps = 2, 128, 4, 2, 32, 16
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, 1, N, h))
-    kc = jax.random.normal(ks[1], (B, S, K, h))
-    vc = jax.random.normal(ks[2], (B, S, K, h))
+    kc = jax.random.normal(ks[1], (B, K, S, h))
+    vc = jax.random.normal(ks[2], (B, K, S, h))
     idx = jnp.asarray([100, 7], jnp.int32)
-    out = attention_decode(q, kc, vc, idx, window=0, opts=opts)
-    exp = attention_decode(q, kc, vc, idx, window=0)
+
+    def route(layout, o):
+        return attention_route("decode", layout, S=1, Skv=S, window=0,
+                               opts=o)
+    assert route("dense", opts) == "decode_flash"
+    assert route("dense", ModelOptions()) == "decode_dense"
+    assert route("paged", opts) == "decode_paged_flash"
+    assert route("paged", ModelOptions()) == "decode_paged_gather"
+    out = run_attention_core("decode_flash", q, kc, vc, opts=opts, window=0,
+                             index=idx)
+    exp = run_attention_core("decode_dense", q, kc, vc, opts=opts, window=0,
+                             index=idx)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                atol=2e-5, rtol=2e-5)
     npg = S // ps
-    kp = kc.reshape(B * npg, ps, K, h)
-    vp = vc.reshape(B * npg, ps, K, h)
+    kp, vp = _pages(kc, ps), _pages(vc, ps)
     pt = jnp.arange(B * npg, dtype=jnp.int32).reshape(B, npg)
-    out_p = attention_decode_paged(q, kp, vp, pt, idx, window=0, opts=opts)
-    exp_p = attention_decode_paged(q, kp, vp, pt, idx, window=0)
+    out_p = run_attention_core("decode_paged_flash", q, kp, vp, opts=opts,
+                               window=0, index=idx, page_table=pt)
+    exp_p = run_attention_core("decode_paged_gather", q, kp, vp, opts=opts,
+                               window=0, index=idx, page_table=pt)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(exp_p),
                                atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(exp),
                                atol=2e-5, rtol=2e-5)
+
+
+def _pages(cache, ps):
+    """Dense head-major [B, K, S, h] cache -> contiguous pool pages
+    [B*S/ps, K, ps, h] (slot b's logical page p is physical b*npg + p)."""
+    B, K, S, h = cache.shape
+    pages = cache.reshape(B, K, S // ps, ps, h).swapaxes(1, 2)
+    return pages.reshape(B * (S // ps), K, ps, h)
 
 
 def test_paged_matches_dense_layout():
@@ -161,11 +181,10 @@ def test_paged_matches_dense_layout():
     B, S, N, K, h, ps = 2, 256, 4, 2, 32, 32
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, N, h))
-    kc = jax.random.normal(ks[1], (B, S, K, h))
-    vc = jax.random.normal(ks[2], (B, S, K, h))
+    kc = jax.random.normal(ks[1], (B, K, S, h))
+    vc = jax.random.normal(ks[2], (B, K, S, h))
     npg = S // ps
-    kp = kc.reshape(B * npg, ps, K, h)
-    vp = vc.reshape(B * npg, ps, K, h)
+    kp, vp = _pages(kc, ps), _pages(vc, ps)
     pt = jnp.arange(B * npg, dtype=jnp.int32).reshape(B, npg)
     idx = jnp.asarray([200, 31], jnp.int32)
     dense = decode_attention(q, kc, vc, idx, bk=128, interpret=True)
@@ -190,8 +209,8 @@ def test_chunk_prefill_attention(B, S, L, N, K, h, bk, window, dtype):
     per-slot start positions straddling band boundaries."""
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, S, N, h), dtype)
-    kc = jax.random.normal(ks[1], (B, L, K, h), dtype)
-    vc = jax.random.normal(ks[2], (B, L, K, h), dtype)
+    kc = jax.random.normal(ks[1], (B, K, L, h), dtype)
+    vc = jax.random.normal(ks[2], (B, K, L, h), dtype)
     idx = jax.random.randint(ks[3], (B,), 0, L - S, jnp.int32)
     out = chunk_prefill_attention(q, kc, vc, idx, window=window, bk=bk,
                                   interpret=True)
@@ -207,8 +226,8 @@ def test_chunk_prefill_band_boundaries(start):
     B, S, L, N, K, h, bk = 1, 9, 64, 4, 2, 32, 16
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, S, N, h))
-    kc = jax.random.normal(ks[1], (B, L, K, h))
-    vc = jax.random.normal(ks[2], (B, L, K, h))
+    kc = jax.random.normal(ks[1], (B, K, L, h))
+    vc = jax.random.normal(ks[2], (B, K, L, h))
     out = chunk_prefill_attention(q, kc, vc, start, bk=bk, interpret=True)
     exp = cref.chunk_prefill_ref(q, kc, vc, start)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
@@ -226,8 +245,8 @@ def test_paged_chunk_prefill_attention(B, S, npg, ps, N, K, h, window):
     P = B * npg + 3
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, S, N, h))
-    kp = jax.random.normal(ks[1], (P, ps, K, h))
-    vp = jax.random.normal(ks[2], (P, ps, K, h))
+    kp = jax.random.normal(ks[1], (P, K, ps, h))
+    vp = jax.random.normal(ks[2], (P, K, ps, h))
     perm = jax.random.permutation(ks[3], jnp.arange(1, P))[:B * npg]
     pt = perm.reshape(B, npg).astype(jnp.int32)
     idx = jax.random.randint(ks[3], (B,), 0, npg * ps - S, jnp.int32)
@@ -247,8 +266,8 @@ def test_paged_chunk_prefill_quantized(kv_dtype):
     P = B * npg + 2
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, S, N, h))
-    kp_f = jax.random.normal(ks[1], (P, ps, K, h))
-    vp_f = jax.random.normal(ks[2], (P, ps, K, h))
+    kp_f = jax.random.normal(ks[1], (P, K, ps, h))
+    vp_f = jax.random.normal(ks[2], (P, K, ps, h))
     perm = jax.random.permutation(ks[3], jnp.arange(1, P))[:B * npg]
     pt = perm.reshape(B, npg).astype(jnp.int32)
     idx = jnp.asarray([3, 70], jnp.int32)
@@ -336,8 +355,8 @@ def test_paged_decode_attention_quantized(window, kv_dtype):
     P = B * npg + 3
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, N, h))
-    kp_f = jax.random.normal(ks[1], (P, ps, K, h))
-    vp_f = jax.random.normal(ks[2], (P, ps, K, h))
+    kp_f = jax.random.normal(ks[1], (P, K, ps, h))
+    vp_f = jax.random.normal(ks[2], (P, K, ps, h))
     perm = jax.random.permutation(ks[3], jnp.arange(1, P))[:B * npg]
     pt = perm.reshape(B, npg).astype(jnp.int32)
     idx = jax.random.randint(ks[3], (B,), 0, npg * ps, jnp.int32)
@@ -362,16 +381,16 @@ def test_quantize_page_rows_roundtrip():
     get scale 0 and decode to exactly 0, and encode(decode(c)) == c at a
     fixed scale (drift-free rewrites)."""
     from repro.models import kv_quant
-    rows = jax.random.normal(KEY, (5, 8, 2, 16)) * \
+    rows = jax.random.normal(KEY, (5, 2, 8, 16)) * \
         jnp.asarray([0.1, 1.0, 10.0, 100.0, 0.0]).reshape(5, 1, 1, 1)
     for dt in (jnp.int8, jnp.float8_e4m3fn):
         codes, scales = kv_quant.quantize_page_rows(rows, dt)
         assert codes.dtype == dt and scales.shape == (5, 2)
-        deq = kv_quant.decode(codes, scales[:, None, :, None])
-        half_code = np.asarray(scales)[:, None, :, None] * \
+        deq = kv_quant.decode(codes, scales[:, :, None, None])
+        half_code = np.asarray(scales)[:, :, None, None] * \
             (0.51 if dt == jnp.int8 else 0.07 * kv_quant.qmax(dt))
         assert np.all(np.abs(np.asarray(deq - rows)) <= half_code + 1e-9)
         assert float(jnp.abs(deq[4]).max()) == 0.0      # zero page -> 0
         assert float(scales[4].max()) == 0.0
-        again = kv_quant.encode(deq, scales[:, None, :, None], dt)
+        again = kv_quant.encode(deq, scales[:, :, None, None], dt)
         assert jnp.array_equal(codes, again)

@@ -312,7 +312,7 @@ def test_engine_pallas_kernel_path_matches_reference(opts):
     einsum engine."""
     from repro.models.layers import ModelOptions
     cfg, params = reduced_params("smollm-135m")
-    popts = ModelOptions(remat=False, use_pallas=True, pallas_interpret=True)
+    popts = ModelOptions(remat=False, use_pallas=True)
     rng = np.random.default_rng(15)
     reqs = [(rng.integers(0, cfg.vocab_size, 9, dtype=np.int32), 4)
             for _ in range(2)]
@@ -497,16 +497,17 @@ def test_scatter_pages_quantizes_and_writes_scales(opts):
         if not is_paged_leaf(path) or is_scale_leaf(path):
             continue
         keys = _path_keys(path)
-        pages = leaf if leaf.ndim == 4 else leaf[0]    # [P, ps, K, h]
+        pages = leaf if leaf.ndim == 4 else leaf[0]    # [P, K, ps, h]
         scales = None
         for p2, l2 in jax.tree_util.tree_leaves_with_path(out):
             if _path_keys(p2) == keys[:-1] + (keys[-1] + "_scale",):
                 scales = l2 if l2.ndim == 2 else l2[0]
-        dense = flat1[keys]                            # [(nb,)1,S,K,h]
+        dense = flat1[keys]                            # [(nb,)1,K,S,h]
         dense = dense if dense.ndim == 4 else dense[0]
-        rows = dense.reshape(2, ps, *dense.shape[2:])  # page-major
+        K, h = dense.shape[1], dense.shape[3]
+        rows = dense[0].reshape(K, 2, ps, h).swapaxes(0, 1)  # page-major
         for i, d in enumerate([2, 4]):
-            deq = kv_quant.decode(pages[d], scales[d][None, :, None])
+            deq = kv_quant.decode(pages[d], scales[d][:, None, None])
             np.testing.assert_allclose(np.asarray(deq), np.asarray(rows[i]),
                                        atol=float(scales[d].max()) * 0.51,
                                        err_msg=str(path))
@@ -523,7 +524,7 @@ def test_update_cache_paged_quantized_monotone_scale():
     from repro.models import kv_quant
     from repro.models.layers import update_cache_paged
     ps, K, h = 4, 2, 8
-    pages = jnp.zeros((3, ps, K, h), jnp.int8)
+    pages = jnp.zeros((3, K, ps, h), jnp.int8)
     scales = jnp.zeros((3, K), jnp.float32)
     pt = jnp.asarray([[1, 2]], jnp.int32)
     small = jnp.full((1, 1, K, h), 0.5, jnp.float32)
@@ -534,9 +535,9 @@ def test_update_cache_paged_quantized_monotone_scale():
     pages, scales = update_cache_paged(pages, big, pt, 1, scales)
     np.testing.assert_allclose(np.asarray(scales[1]), 2.0 / 127.0, rtol=1e-6)
     # row 0 (written under the smaller scale) survived the requantization
-    deq = kv_quant.decode(pages[1], np.asarray(scales[1])[None, :, None])
-    np.testing.assert_allclose(np.asarray(deq[0]), 0.5, atol=2.0 / 127.0)
-    np.testing.assert_allclose(np.asarray(deq[1]), 2.0, atol=2.0 / 127.0)
+    deq = kv_quant.decode(pages[1], np.asarray(scales[1])[:, None, None])
+    np.testing.assert_allclose(np.asarray(deq[:, 0]), 0.5, atol=2.0 / 127.0)
+    np.testing.assert_allclose(np.asarray(deq[:, 1]), 2.0, atol=2.0 / 127.0)
     # writing a smaller token later must not shrink the scale (monotone)...
     pages, scales = update_cache_paged(pages, small, pt, 2, scales)
     np.testing.assert_allclose(np.asarray(scales[1]), 2.0 / 127.0, rtol=1e-6)
@@ -591,7 +592,7 @@ def test_quantized_null_page_stays_zero(opts):
     is a sink; e2e: an engine run with an idle slot leaves page 0 clean)."""
     from repro.models.layers import update_cache_paged
     from repro.models.stacks import is_paged_leaf, is_scale_leaf
-    pages = jnp.zeros((3, 4, 2, 8), jnp.int8)
+    pages = jnp.zeros((3, 2, 4, 8), jnp.int8)
     scales = jnp.zeros((3, 2), jnp.float32)
     pt = jnp.asarray([[0, 0], [1, 2]], jnp.int32)     # slot 0 retired
     new = jnp.full((2, 1, 2, 8), 3.0, jnp.float32)
