@@ -1,0 +1,7 @@
+"""The benchmark's own tests: ``python3 -m pytest bench/tests`` from the
+checkout's root, on the CPU (``JAX_PLATFORMS=cpu``)."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+sys.path[:0] = [str(BENCH), str(BENCH.parent / "src")]
