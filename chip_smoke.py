@@ -138,7 +138,8 @@ def serve(cfg, params, reqs, max_seq: int, mesh=None):
     if bad:
         raise RuntimeError(f"token ids out of the vocabulary in {bad}")
     st = eng.stats
-    if not st.vision_time > 0:
+    ph = st.phase_report()
+    if not ph["vision"] > 0:
         raise RuntimeError("the vision tower never ran")
     if st.prefill_tokens != len(reqs) * n_prompt:
         raise RuntimeError(f"prefilled {st.prefill_tokens} positions, not "
@@ -148,7 +149,7 @@ def serve(cfg, params, reqs, max_seq: int, mesh=None):
            f"tokens, {n_prompt} prompt positions each (vision "
            f"{cfg.vision.num_tokens} + text {cfg.n_prompt_tokens}); wall "
            f"{wall:.3f}s incl. compilation (bring-up, not a benchmark); "
-           f"vision {st.vision_time:.3f}s prefill {st.prefill_time:.3f}s; "
+           f"vision {ph['vision']:.3f}s prefill {ph['prefill']:.3f}s; "
            f"{st.decode_syncs} decode syncs / {st.device_steps} device "
            f"steps; pages_hwm={st.pages_hwm}")
     return eng, toks

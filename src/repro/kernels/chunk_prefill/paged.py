@@ -112,6 +112,7 @@ def paged_chunk_prefill_attention_kernel(q, k_pages, v_pages, page_table,
         operands += [token_scales(k_scales, ps), token_scales(v_scales, ps)]
     out = pl.pallas_call(
         kernel,
+        name="paged_chunk_prefill_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, N, npg),

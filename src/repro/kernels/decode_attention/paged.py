@@ -136,6 +136,7 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, page_table, index, *,
         operands += [token_scales(k_scales, ps), token_scales(v_scales, ps)]
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, K, npg),

@@ -63,13 +63,18 @@ Two admission policies:
   non-shared token), and pool admission becomes chunk-granular with a
   decode-headroom reserve + longest-idle eviction. See docs/scheduler.md.
 
-Phase latency accounting (vision / prefill / decode) is recorded per request
-and aggregated in ``EngineStats`` — the serving-side counterpart of the
-paper's Nsight phase decomposition — and survives the fusion: vision runs as
-its own jitted stage (``M.encode_vision`` feeding ``batch['prefix']``), and
-decode wall-time is attributed per tick. Per-request ``queue_s``/``ttft_s``
-and per-tick latency lists (``tick_s``/``decode_tick_s``, with p50/p99 in
-``phase_report()``) make scheduler jitter observable.
+Phase latency accounting (vision / prefill / decode) comes from the
+engine's spans (``serving.tracing``; ``EngineStats.spans``): every tick,
+admission, vision run, chunk, first-token read-back, decode dispatch,
+read-back and emit is a named span on the host clock and, with a profiler
+trace on, on the device trace's. ``phase_report()`` sums them — the
+serving-side counterpart of the paper's Nsight phase decomposition — and
+survives the fusion: vision runs as its own jitted stage
+(``M.encode_vision`` feeding ``batch['prefix']``). Per-request
+``queue_s``/``ttft_s``, per-tick ``tick_s`` and the decode-tick p50/p99 in
+``phase_report()`` make scheduler jitter observable. The stage programs are
+named ``jit_serve_tick``, ``jit_serve_spec_tick``, ``jit_serve_vision`` and
+``jit_serve_chunk`` in profiles and HLO dumps.
 """
 from __future__ import annotations
 
@@ -102,6 +107,7 @@ from repro.serving.scheduler import (BEST_EFFORT, ChunkedScheduler, ChunkPlan,
                                      PrefillTask, SLOController,
                                      eviction_victims, insert_by_class,
                                      is_realtime, req_deadline)
+from repro.serving.tracing import SpanRecorder, span
 
 
 @dataclass
@@ -128,6 +134,8 @@ class Request:
     #                                    submit time
     t_deadline: float = math.inf       # t_submit + deadline_s (set by
     #                                    ServingEngine.submit; inf = none)
+    trace: Optional[SpanRecorder] = None  # where this request's spans go
+    #                                    (set by ServingEngine.submit)
 
 
 @dataclass
@@ -147,13 +155,12 @@ class EngineStats:
     pages served from the prefix cache instead of being re-stored.
     """
     decode_syncs: int = 0       # blocking readbacks on the decode path
-    prefill_syncs: int = 0      # blocking readbacks at admission
     ticks: int = 0              # engine ticks (fused or reference)
     device_steps: int = 0       # decode steps executed on device
     tokens_decoded: int = 0     # tokens emitted by the decode path
-    vision_time: float = 0.0
-    prefill_time: float = 0.0
-    decode_time: float = 0.0
+    # the engine's spans (serving.tracing): a bounded ring plus running
+    # totals per span name; the phase split below is read from them
+    spans: SpanRecorder = field(default_factory=SpanRecorder)
     prefill_tokens: int = 0     # prompt positions actually run through prefill
     prefill_skipped: int = 0    # prompt positions skipped via prefix-cache hit
     # key-lane accounting for the banded prefill-with-cache core: per prefill
@@ -186,7 +193,6 @@ class EngineStats:
     queue_s: List[float] = field(default_factory=list)
     ttft_s: List[float] = field(default_factory=list)
     tick_s: List[float] = field(default_factory=list)    # whole-tick wall
-    decode_tick_s: List[float] = field(default_factory=list)  # decode stage
     tick_prefill_tokens: List[int] = field(default_factory=list)  # per tick:
     # prompt positions prefilled inside that tick — the head-of-line metric
     # (admit-stall pays a whole prompt in one tick; the scheduler's entry
@@ -240,11 +246,26 @@ class EngineStats:
         self.preemptions[req.priority] = \
             self.preemptions.get(req.priority, 0) + 1
 
+    def decode_tick_s(self) -> List[float]:
+        """Per tick, seconds from its decode dispatch (``engine.decode``,
+        page growth included) to the end of its read-back
+        (``engine.readback``), over the ticks the span ring still holds."""
+        ring = self.spans.spans()
+        start = {s.parent: s.t0 for s in ring if s.name == "engine.decode"}
+        return [s.t1 - start[s.parent] for s in ring
+                if s.name == "engine.readback" and s.parent in start]
+
     def phase_report(self) -> Dict[str, float]:
         """Figure-2-style wall-time decomposition, plus decode-tick latency
         percentiles (p50/p99 over the per-tick decode stage) so scheduler
         jitter — a prefill chunk crowding the tick a decoder needed — is
-        observable, not just the aggregate mean. When prefill ran,
+        observable, not just the aggregate mean. The phases are span
+        totals (host seconds): ``vision`` is ``engine.vision`` (the tower
+        through its ``block_until_ready``); ``prefill`` is every admission
+        less its vision plus every chunk, so it ends at each request's
+        first-token read-back (``engine.first_token``, inside the last
+        chunk); ``decode`` is ``engine.decode`` (page growth and dispatch)
+        plus ``engine.readback``. When prefill ran,
         ``prefill_key_lane_ratio`` is the banded core's key-axis work over
         the old full-``max_seq``-view equivalent — the paper-style phase
         accounting for the recovered ~max_seq/S prefill factor. Per-request
@@ -253,13 +274,17 @@ class EngineStats:
         make a stalled fleet diagnosable from a front-end log line: a
         growing queue_p99 with flat decode percentiles means admission is
         starved (pool pressure / backlog), not that decode got slower."""
-        rep = {"vision": self.vision_time, "prefill": self.prefill_time,
-               "decode": self.decode_time}
-        if self.decode_tick_s:
-            rep["decode_tick_p50"] = float(np.percentile(self.decode_tick_s,
-                                                         50))
-            rep["decode_tick_p99"] = float(np.percentile(self.decode_tick_s,
-                                                         99))
+        tot = self.spans.totals
+        vision = tot.get("engine.vision", 0.0)
+        rep = {"vision": vision,
+               "prefill": (tot.get("engine.admit", 0.0) - vision
+                           + tot.get("engine.chunk", 0.0)),
+               "decode": (tot.get("engine.decode", 0.0)
+                          + tot.get("engine.readback", 0.0))}
+        ticks = self.decode_tick_s()
+        if ticks:
+            rep["decode_tick_p50"] = float(np.percentile(ticks, 50))
+            rep["decode_tick_p99"] = float(np.percentile(ticks, 99))
         for name, samples in (("queue", self.queue_s), ("ttft", self.ttft_s)):
             if samples:
                 rep[f"{name}_p50"] = float(np.percentile(samples, 50))
@@ -517,9 +542,15 @@ def _jit_prefill(cfg: ModelConfig, opts: ModelOptions, max_seq: int,
                                           cache_dtype=cache_dtype))
 
 
+# The stage programs are named functions, so a profile or an HLO dump shows
+# them as jit_serve_vision / jit_serve_chunk / jit_serve_tick /
+# jit_serve_spec_tick. The vision program's first argument stays ``p``: XLA
+# names the weight leaves after it (``p__vision__...``).
 @functools.lru_cache(maxsize=None)
 def _jit_vision(cfg: ModelConfig, opts: ModelOptions):
-    return jax.jit(lambda p, px: M.encode_vision(cfg, opts, p, px))
+    def serve_vision(p, px):
+        return M.encode_vision(cfg, opts, p, px)
+    return jax.jit(serve_vision)
 
 
 @functools.lru_cache(maxsize=None)
@@ -533,24 +564,27 @@ def _jit_prefill_chunk(cfg: ModelConfig, opts: ModelOptions, paged: bool):
     ``max_seq / prefill_band`` distinct values per chunk shape. Caches are
     donated — the engine rebinds the returned tree."""
     if paged:
-        return jax.jit(
-            lambda p, e, c, i, nv, pt, live: M.prefill_chunk(
-                cfg, opts, p, e, c, i, n_valid=nv, page_table=pt,
-                live_len=live),
-            donate_argnums=2, static_argnums=6)
-    return jax.jit(
-        lambda p, e, c, i, nv, live: M.prefill_chunk(
-            cfg, opts, p, e, c, i, n_valid=nv, live_len=live),
-        donate_argnums=2, static_argnums=5)
+        def serve_chunk(p, e, c, i, nv, pt, live):
+            return M.prefill_chunk(cfg, opts, p, e, c, i, n_valid=nv,
+                                   page_table=pt, live_len=live)
+        return jax.jit(serve_chunk, donate_argnums=2, static_argnums=6)
+
+    def serve_chunk(p, e, c, i, nv, live):
+        return M.prefill_chunk(cfg, opts, p, e, c, i, n_valid=nv,
+                               live_len=live)
+    return jax.jit(serve_chunk, donate_argnums=2, static_argnums=5)
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_tick(cfg: ModelConfig, opts: ModelOptions, tick_tokens: int,
               eos: int, temperature: float, top_k: int,
               stop_on_finish: bool):
-    return jax.jit(functools.partial(_fused_tick, cfg, opts, tick_tokens,
-                                     eos, temperature, top_k,
-                                     stop_on_finish))
+    def serve_tick(params, tokens, caches, index, budget, done, key,
+                   max_steps, page_table=None):
+        return _fused_tick(cfg, opts, tick_tokens, eos, temperature, top_k,
+                           stop_on_finish, params, tokens, caches, index,
+                           budget, done, key, max_steps, page_table)
+    return jax.jit(serve_tick)
 
 
 @functools.lru_cache(maxsize=None)
@@ -562,10 +596,10 @@ def _jit_spec_tick(cfg: ModelConfig, opts: ModelOptions, tick_tokens: int,
     it to whole bands, so it takes at most ``max_seq / prefill_band``
     distinct values. Dense engines pass ``page_table=None`` (an empty
     pytree, not a trace problem)."""
-    return jax.jit(functools.partial(_fused_spec_tick, cfg, opts,
-                                     tick_tokens, spec_k, draft_blocks, eos,
-                                     stop_on_finish, max_seq),
-                   static_argnums=0)
+    def serve_spec_tick(live_len, *args):
+        return _fused_spec_tick(cfg, opts, tick_tokens, spec_k, draft_blocks,
+                                eos, stop_on_finish, max_seq, live_len, *args)
+    return jax.jit(serve_spec_tick, static_argnums=0)
 
 
 class ServingEngine:
@@ -905,40 +939,51 @@ class ServingEngine:
             (pspecs, R, c1specs), (R, c1specs)))
         self._prefill = lambda p, b: prefill_sharded(p, b,
                                                      self._cache1_zeros)
-        self._tick = jax.jit(smap(
-            functools.partial(_fused_tick, cfg, shopts, self.tick_tokens,
-                              self.eos, self.temperature, self.top_k,
-                              stop_on_finish),
-            (pspecs, R, cspecs, R, R, R, R, R, R),
-            (R, cspecs, R, R, R, R, R, R, R)))
+        tick_tokens, eos = self.tick_tokens, self.eos
+        temperature, top_k = self.temperature, self.top_k
+
+        def serve_tick(params, tokens, caches, index, budget, done, key,
+                       max_steps, page_table):
+            return _fused_tick(cfg, shopts, tick_tokens, eos, temperature,
+                               top_k, stop_on_finish, params, tokens, caches,
+                               index, budget, done, key, max_steps,
+                               page_table)
+        self._tick = jax.jit(smap(serve_tick,
+                                  (pspecs, R, cspecs, R, R, R, R, R, R),
+                                  (R, cspecs, R, R, R, R, R, R, R)))
         if self.spec_decode:
-            def spec_tick(live_len, p, dp, t, c, i, b, d, ms, pt):
-                f = functools.partial(
-                    _fused_spec_tick, cfg, shopts, self.tick_tokens,
-                    self.spec_k, self.draft_blocks, self.eos,
-                    stop_on_finish, self.max_seq, live_len)
+            spec_k, draft_blocks = self.spec_k, self.draft_blocks
+            max_seq = self.max_seq
+
+            def serve_spec_tick(live_len, p, dp, t, c, i, b, d, ms, pt):
+                def f(*args):
+                    return _fused_spec_tick(cfg, shopts, tick_tokens, spec_k,
+                                            draft_blocks, eos, stop_on_finish,
+                                            max_seq, live_len, *args)
                 return smap(f, (pspecs, pspecs, R, cspecs, R, R, R, R, R),
                             (R, cspecs, R, R, R, R, R, R, R, R))(
                     p, dp, t, c, i, b, d, ms, pt)
-            self._spec_tick = jax.jit(spec_tick, static_argnums=0)
+            self._spec_tick = jax.jit(serve_spec_tick, static_argnums=0)
         if self.scheduler is not None:
             if self.paged:
-                def prefill_chunk(p, e, c, i, nv, pt, live):
-                    f = lambda p, e, c, i, nv, pt: M.prefill_chunk(
-                        cfg, shopts, p, e, c, i, n_valid=nv, page_table=pt,
-                        live_len=live)
+                def serve_chunk(p, e, c, i, nv, pt, live):
+                    def f(p, e, c, i, nv, pt):
+                        return M.prefill_chunk(cfg, shopts, p, e, c, i,
+                                               n_valid=nv, page_table=pt,
+                                               live_len=live)
                     return smap(f, (pspecs, R, cspecs, R, R, R),
                                 (R, cspecs))(p, e, c, i, nv, pt)
-                self._prefill_chunk = jax.jit(prefill_chunk,
+                self._prefill_chunk = jax.jit(serve_chunk,
                                               donate_argnums=2,
                                               static_argnums=6)
             else:
-                def prefill_chunk(p, e, c, i, nv, live):
-                    f = lambda p, e, c, i, nv: M.prefill_chunk(
-                        cfg, shopts, p, e, c, i, n_valid=nv, live_len=live)
+                def serve_chunk(p, e, c, i, nv, live):
+                    def f(p, e, c, i, nv):
+                        return M.prefill_chunk(cfg, shopts, p, e, c, i,
+                                               n_valid=nv, live_len=live)
                     return smap(f, (pspecs, R, c1specs, R, R),
                                 (R, c1specs))(p, e, c, i, nv)
-                self._prefill_chunk = jax.jit(prefill_chunk,
+                self._prefill_chunk = jax.jit(serve_chunk,
                                               donate_argnums=2,
                                               static_argnums=5)
 
@@ -997,6 +1042,7 @@ class ServingEngine:
     # -- queue -----------------------------------------------------------
     def submit(self, req: Request):
         req.t_submit = time.perf_counter()
+        req.trace = self.stats.spans
         # the relative SLO becomes absolute at submit: everything
         # downstream (EDF ordering, the SLO controller, attainment
         # scoring) compares wall clock against this one stamp
@@ -1299,96 +1345,103 @@ class ServingEngine:
             # at prefill (EOS first token, or max_tokens == 1)
             while self.slots[s] is None and self.queue:
                 req = self.queue[0]
-                n_prefix = (self.cfg.vision.num_tokens
-                            if req.patches is not None and self._vision
-                            else 0)
-                pos = n_prefix + len(req.prompt)
-                keys = (self._prefix_page_keys(req, n_prefix)
-                        if self.paged else [])
-                # capacity must cover the prompt AND the first decode write
-                # at position pos (requests finishing at prefill need none)
-                need = (0 if req.max_tokens <= 1
-                        else min(pos + 1, self.max_seq))
-                if self.paged and need and not self.pool.can_admit(need,
-                                                                   keys):
-                    if not any(r is not None for r in self.slots):
-                        # nothing in flight will ever free pages: sizing error
-                        raise PoolExhausted(
-                            f"KV pool ({self.pool.num_pages - 1} pages) too "
-                            f"small for request {req.uid} "
-                            f"({self.pool.num_pages_for(need)} pages)")
-                    # defer *before* paying for vision + prefill; retry when
-                    # a finishing slot frees pages
-                    return
-                self.queue.pop(0)
-                t0 = time.perf_counter()
-                req.queue_s = t0 - req.t_submit
-                self.stats.queue_s.append(req.queue_s)
-                batch = {"tokens": jnp.asarray(req.prompt[None, :])}
-                if n_prefix:
-                    prefix = self._vision(self.params,
-                                          jnp.asarray(req.patches[None]))
-                    prefix.block_until_ready()
-                    t1 = time.perf_counter()
-                    self.stats.vision_time += t1 - t0
-                    batch["prefix"] = prefix
-                    t0 = t1
-                logits, cache1 = self._prefill(self.params, batch)
-                tok = int(self._sample_host(logits)[0])
-                self.stats.prefill_syncs += 1
-                req.t_prefill = time.perf_counter()
-                self.stats.prefill_time += req.t_prefill - t0
-                self.stats.prefill_tokens += pos
-                # monolithic prefill attends the banded live prefix too
-                # (model.prefill derives live_len from the prompt shape)
-                self.stats.prefill_key_lanes += pos * band_len(
-                    pos, self.opts.prefill_band, self.max_seq)
-                self.stats.prefill_key_lanes_full += pos * self.max_seq
-                req.ttft_s = req.t_prefill - req.t_submit
-                self.stats.ttft_s.append(req.ttft_s)
-                req.out_tokens.append(tok)
-                budget = self._clamped_budget(req, pos)
-                if tok == self.eos or req.max_tokens <= 1 or budget <= 0:
-                    req.done = True
-                    req.t_done = req.t_prefill
-                    self.stats.record_deadline(req)
-                    self.finished.append(req)
-                    continue
-                if self.paged:
-                    try:
-                        pages, n_shared = self.pool.admit(s, pos, keys)
-                    except PoolExhausted:
-                        # can_admit() raced a cached-page eviction; defer
-                        # and roll the attempt's stats back too, so the
-                        # retry doesn't double-count this request
-                        self.queue.insert(0, req)
-                        req.out_tokens.pop()
-                        self.stats.queue_s.pop()
-                        self.stats.ttft_s.pop()
-                        self.stats.prefill_tokens -= pos
-                        self.stats.prefill_key_lanes -= pos * band_len(
-                            pos, self.opts.prefill_band, self.max_seq)
-                        self.stats.prefill_key_lanes_full -= pos * self.max_seq
+                with span("engine.admit", self.stats.spans, uid=req.uid):
+                    if not self._admit_one(req, s):
                         return
-                    req.pages_used = len(pages)
-                    req.pages_shared = n_shared
-                    # shared pages already hold this prefix's KV — route
-                    # their rows to the null sink instead of re-writing
-                    dest = np.zeros(self.pool.pages_per_slot, np.int32)
-                    dest[n_shared:len(pages)] = pages[n_shared:]
-                    self.caches = self._scatter_pages_fn(self.caches, cache1,
-                                                         jnp.asarray(dest))
-                    self.caches = self._scatter_slot_fn(self.caches, cache1,
-                                                        s, True)
-                    self._update_cache_stats()
-                else:
-                    self.caches = self._scatter_slot_fn(self.caches, cache1,
-                                                        s, False)
-                self.index[s] = pos
-                self.budget[s] = budget
-                self.tokens[s, 0] = tok
-                self.slots[s] = req
-                self._last_active[s] = req.t_prefill
+
+    def _admit_one(self, req: Request, s: int) -> bool:
+        """One monolithic admission attempt of the queue's head ``req``
+        into slot ``s``: capacity check, vision, prefill, first token,
+        scatter. False when the request has to wait (deferred for pages,
+        or the pool raced it back to the queue's head)."""
+        n_prefix = (self.cfg.vision.num_tokens
+                    if req.patches is not None and self._vision
+                    else 0)
+        pos = n_prefix + len(req.prompt)
+        keys = (self._prefix_page_keys(req, n_prefix)
+                if self.paged else [])
+        # capacity must cover the prompt AND the first decode write
+        # at position pos (requests finishing at prefill need none)
+        need = (0 if req.max_tokens <= 1
+                else min(pos + 1, self.max_seq))
+        if self.paged and need and not self.pool.can_admit(need,
+                                                           keys):
+            if not any(r is not None for r in self.slots):
+                # nothing in flight will ever free pages: sizing error
+                raise PoolExhausted(
+                    f"KV pool ({self.pool.num_pages - 1} pages) too "
+                    f"small for request {req.uid} "
+                    f"({self.pool.num_pages_for(need)} pages)")
+            # defer *before* paying for vision + prefill; retry when
+            # a finishing slot frees pages
+            return False
+        self.queue.pop(0)
+        rec = self.stats.spans
+        req.queue_s = time.perf_counter() - req.t_submit
+        self.stats.queue_s.append(req.queue_s)
+        batch = {"tokens": jnp.asarray(req.prompt[None, :])}
+        if n_prefix:
+            with span("engine.vision", rec, uid=req.uid):
+                prefix = self._vision(self.params,
+                                      jnp.asarray(req.patches[None]))
+                prefix.block_until_ready()
+            batch["prefix"] = prefix
+        with span("engine.first_token", rec, uid=req.uid):
+            logits, cache1 = self._prefill(self.params, batch)
+            tok = int(self._sample_host(logits)[0])
+        req.t_prefill = time.perf_counter()
+        self.stats.prefill_tokens += pos
+        # monolithic prefill attends the banded live prefix too
+        # (model.prefill derives live_len from the prompt shape)
+        self.stats.prefill_key_lanes += pos * band_len(
+            pos, self.opts.prefill_band, self.max_seq)
+        self.stats.prefill_key_lanes_full += pos * self.max_seq
+        req.ttft_s = req.t_prefill - req.t_submit
+        self.stats.ttft_s.append(req.ttft_s)
+        req.out_tokens.append(tok)
+        budget = self._clamped_budget(req, pos)
+        if tok == self.eos or req.max_tokens <= 1 or budget <= 0:
+            req.done = True
+            req.t_done = req.t_prefill
+            self.stats.record_deadline(req)
+            self.finished.append(req)
+            return True
+        if self.paged:
+            try:
+                pages, n_shared = self.pool.admit(s, pos, keys)
+            except PoolExhausted:
+                # can_admit() raced a cached-page eviction; defer
+                # and roll the attempt's stats back too, so the
+                # retry doesn't double-count this request
+                self.queue.insert(0, req)
+                req.out_tokens.pop()
+                self.stats.queue_s.pop()
+                self.stats.ttft_s.pop()
+                self.stats.prefill_tokens -= pos
+                self.stats.prefill_key_lanes -= pos * band_len(
+                    pos, self.opts.prefill_band, self.max_seq)
+                self.stats.prefill_key_lanes_full -= pos * self.max_seq
+                return False
+            req.pages_used = len(pages)
+            req.pages_shared = n_shared
+            # shared pages already hold this prefix's KV — route
+            # their rows to the null sink instead of re-writing
+            dest = np.zeros(self.pool.pages_per_slot, np.int32)
+            dest[n_shared:len(pages)] = pages[n_shared:]
+            self.caches = self._scatter_pages_fn(self.caches, cache1,
+                                                 jnp.asarray(dest))
+            self.caches = self._scatter_slot_fn(self.caches, cache1,
+                                                s, True)
+            self._update_cache_stats()
+        else:
+            self.caches = self._scatter_slot_fn(self.caches, cache1,
+                                                s, False)
+        self.index[s] = pos
+        self.budget[s] = budget
+        self.tokens[s, 0] = tok
+        self.slots[s] = req
+        self._last_active[s] = req.t_prefill
+        return True
 
     # -- one engine tick ---------------------------------------------------
     def step(self) -> int:
@@ -1396,6 +1449,10 @@ class ServingEngine:
         if self.scheduler is not None:
             raise RuntimeError("chunked_prefill engines tick via "
                                "step_fused()/run() (fused only)")
+        with span("engine.tick", self.stats.spans):
+            return self._step_reference()
+
+    def _step_reference(self) -> int:
         t_tick = time.perf_counter()
         pf0 = self.stats.prefill_tokens
         kl0 = self.stats.prefill_key_lanes
@@ -1410,36 +1467,37 @@ class ServingEngine:
             self.stats.tick_s.append(wall)
             self.stats.record_tick_wall(wall)
             return 0
-        pt = None
-        if self.paged:
-            self._ensure_pages(1)
-            pt = self._page_table_device()
-            # growth may have preempted a slot under pool pressure
-            active = [s for s in range(self.n_slots)
-                      if self.slots[s] is not None]
-        t0 = time.perf_counter()
-        logits, self.caches = self._decode(
-            self.params, jnp.asarray(self.tokens), self.caches,
-            jnp.asarray(self.index), pt)
-        nxt = np.asarray(self._sample_host(logits))
-        now = time.perf_counter()
-        self.stats.decode_syncs += 1
-        self.stats.ticks += 1
-        self.stats.device_steps += 1
-        self.stats.tokens_decoded += len(active)
-        self.stats.decode_time += now - t0
-        self.stats.decode_tick_s.append(now - t0)
-        for s in active:
-            req = self.slots[s]
-            tok = int(nxt[s])
-            req.out_tokens.append(tok)
-            self._last_active[s] = now
-            self.index[s] += 1
-            self.budget[s] -= 1
-            if tok == self.eos or self.budget[s] <= 0:
-                self._finish_slot(s, now)
-            else:
-                self.tokens[s, 0] = tok
+        rec = self.stats.spans
+        with span("engine.decode", rec):
+            pt = None
+            if self.paged:
+                self._ensure_pages(1)
+                pt = self._page_table_device()
+                # growth may have preempted a slot under pool pressure
+                active = [s for s in range(self.n_slots)
+                          if self.slots[s] is not None]
+            logits, self.caches = self._decode(
+                self.params, jnp.asarray(self.tokens), self.caches,
+                jnp.asarray(self.index), pt)
+        with span("engine.readback", rec):
+            nxt = np.asarray(self._sample_host(logits))
+        with span("engine.emit", rec):
+            now = time.perf_counter()
+            self.stats.decode_syncs += 1
+            self.stats.ticks += 1
+            self.stats.device_steps += 1
+            self.stats.tokens_decoded += len(active)
+            for s in active:
+                req = self.slots[s]
+                tok = int(nxt[s])
+                req.out_tokens.append(tok)
+                self._last_active[s] = now
+                self.index[s] += 1
+                self.budget[s] -= 1
+                if tok == self.eos or self.budget[s] <= 0:
+                    self._finish_slot(s, now)
+                else:
+                    self.tokens[s, 0] = tok
         self.stats.tick_prefill_tokens.append(
             self.stats.prefill_tokens - pf0)
         self.stats.tick_key_lanes.append(
@@ -1453,8 +1511,12 @@ class ServingEngine:
         """Fused path: up to ``tick_tokens`` decode steps per host sync.
         With ``chunked_prefill`` the tick additionally packs prefill chunks
         under the token budget (see ``_tick_chunked``)."""
-        if self.scheduler is not None:
-            return self._tick_chunked()
+        with span("engine.tick", self.stats.spans):
+            if self.scheduler is not None:
+                return self._tick_chunked()
+            return self._tick_admit_stall()
+
+    def _tick_admit_stall(self) -> int:
         t_tick = time.perf_counter()
         pf0 = self.stats.prefill_tokens
         kl0 = self.stats.prefill_key_lanes
@@ -1479,36 +1541,48 @@ class ServingEngine:
         active = [s for s in range(self.n_slots) if self.slots[s] is not None]
         if not active:
             return 0
-        pt = None
-        if self.paged:
-            self._ensure_pages(min(max_steps, self.tick_tokens),
-                               extra=self.spec_k - 1 if self.spec_decode
-                               else 0)
-            pt = self._page_table_device()
-            # growth may have preempted a slot under pool pressure
-            active = [s for s in range(self.n_slots)
-                      if self.slots[s] is not None]
-            if not active:
-                return 0
-        if self.spec_decode:
-            return self._decode_tick_spec(max_steps, active, pt)
-        t0 = time.perf_counter()
-        done0 = np.asarray([self.slots[s] is None
-                            for s in range(self.n_slots)])
-        (tokens, self.caches, index, budget, done, self.key, out, n_emit,
-         steps) = self._tick(
-            self.params, jnp.asarray(self.tokens), self.caches,
-            jnp.asarray(self.index), jnp.asarray(self.budget),
-            jnp.asarray(done0), self.key,
-            jnp.asarray(max_steps, jnp.int32), pt)
-        out_h, n_emit_h, idx_h, bud_h, done_h, tok_h, steps_h = \
-            jax.device_get((out, n_emit, index, budget, done, tokens, steps))
+        rec = self.stats.spans
+        with span("engine.decode", rec):
+            pt = None
+            if self.paged:
+                self._ensure_pages(min(max_steps, self.tick_tokens),
+                                   extra=self.spec_k - 1 if self.spec_decode
+                                   else 0)
+                pt = self._page_table_device()
+                # growth may have preempted a slot under pool pressure
+                active = [s for s in range(self.n_slots)
+                          if self.slots[s] is not None]
+                if not active:
+                    return 0
+            if self.spec_decode:
+                outs, bounds = self._dispatch_spec_tick(max_steps, active, pt)
+            else:
+                done0 = np.asarray([self.slots[s] is None
+                                    for s in range(self.n_slots)])
+                (tokens, self.caches, index, budget, done, self.key, out,
+                 n_emit, steps) = self._tick(
+                    self.params, jnp.asarray(self.tokens), self.caches,
+                    jnp.asarray(self.index), jnp.asarray(self.budget),
+                    jnp.asarray(done0), self.key,
+                    jnp.asarray(max_steps, jnp.int32), pt)
+                outs = (out, n_emit, index, budget, done, tokens, steps)
+        with span("engine.readback", rec):
+            got = jax.device_get(outs)
+        with span("engine.emit", rec):
+            if self.spec_decode:
+                return self._emit_spec_tick(got, bounds, active)
+            out_h, n_emit_h, idx_h, bud_h, done_h, tok_h, steps_h = got
+            self.stats.decode_syncs += 1
+            self.stats.ticks += 1
+            self.stats.device_steps += int(steps_h)
+            return self._emit(active, out_h, n_emit_h, idx_h, bud_h, done_h,
+                              tok_h)
+
+    def _emit(self, active: List[int], out_h, n_emit_h, idx_h, bud_h,
+              done_h, tok_h) -> int:
+        """Hand a tick's read-back tokens to their requests and finish the
+        slots that are done; returns the tokens emitted."""
         now = time.perf_counter()
-        self.stats.decode_syncs += 1
-        self.stats.ticks += 1
-        self.stats.device_steps += int(steps_h)
-        self.stats.decode_time += now - t0
-        self.stats.decode_tick_s.append(now - t0)
         self.index = np.array(idx_h, np.int32)
         self.budget = np.array(bud_h, np.int32)
         self.tokens = np.array(tok_h, np.int32)
@@ -1525,14 +1599,12 @@ class ServingEngine:
         self.stats.tokens_decoded += emitted
         return emitted
 
-    def _decode_tick_spec(self, max_steps: int, active: List[int],
-                          pt) -> int:
+    def _dispatch_spec_tick(self, max_steps: int, active: List[int], pt):
         """Speculative decode stage: draft -> verify -> accept rounds on
         device (``_fused_spec_tick``), one host sync. Called by
         ``_decode_tick`` after page growth reserved ``spec_k - 1`` extra
-        write rows per slot."""
-        t0 = time.perf_counter()
-        st = self.stats
+        write rows per slot; returns the tick's device outputs and each
+        live slot's verify key bound."""
         K = self.spec_k
         cap = int(min(max_steps, self.tick_tokens))
         idx0 = self.index.copy()
@@ -1555,10 +1627,17 @@ class ServingEngine:
             jnp.asarray(self.tokens), self.caches, jnp.asarray(self.index),
             jnp.asarray(self.budget), jnp.asarray(done0),
             jnp.asarray(max_steps, jnp.int32), pt)
+        return ((out, e, index, budget, done, tokens, hist, passes, iters),
+                bounds)
+
+    def _emit_spec_tick(self, got, bounds: Dict[int, int],
+                        active: List[int]) -> int:
+        """The host half of a speculative tick: accounting of its verify
+        passes and accepted drafts, then the tokens to their requests."""
         (out_h, e_h, idx_h, bud_h, done_h, tok_h, hist_h, passes_h,
-         iters_h) = jax.device_get((out, e, index, budget, done, tokens,
-                                    hist, passes, iters))
-        now = time.perf_counter()
+         iters_h) = got
+        st = self.stats
+        K = self.spec_k
         st.decode_syncs += 1
         st.ticks += 1
         # one loop round == one full-model pass (the verify chunk), same
@@ -1577,23 +1656,7 @@ class ServingEngine:
         for s in active:
             st.spec_key_lanes += int(passes_h[s]) * K * bounds[s]
             st.spec_key_lanes_full += int(passes_h[s]) * K * self.max_seq
-        st.decode_time += now - t0
-        st.decode_tick_s.append(now - t0)
-        self.index = np.array(idx_h, np.int32)
-        self.budget = np.array(bud_h, np.int32)
-        self.tokens = np.array(tok_h, np.int32)
-        emitted = 0
-        for s in active:
-            req = self.slots[s]
-            k = int(e_h[s])
-            req.out_tokens.extend(int(t) for t in out_h[s, :k])
-            emitted += k
-            if k:
-                self._last_active[s] = now
-            if done_h[s]:
-                self._finish_slot(s, now)
-        st.tokens_decoded += emitted
-        return emitted
+        return self._emit(active, out_h, e_h, idx_h, bud_h, done_h, tok_h)
 
     # -- chunked-prefill scheduler mode ------------------------------------
     def _reset_fresh_scales(self, fresh: List[int]):
@@ -1626,106 +1689,114 @@ class ServingEngine:
             while (sched.waiting and self.slots[s] is None
                    and s not in sched.tasks):
                 req = sched.waiting[0]
-                n_prefix = (self.cfg.vision.num_tokens
-                            if req.patches is not None and self._vision
-                            else 0)
-                total = n_prefix + len(req.prompt)
-                if total > self.max_seq:
-                    raise ValueError(
-                        f"request {req.uid}: prompt ({total} positions) "
-                        f"exceeds max_seq {self.max_seq}")
-                n_skip = 0
-                keys: List[bytes] = []
-                if self.paged:
-                    keys = self._prefix_page_keys(req, n_prefix)
-                    n_hit = self.pool.match_prefix(keys)
-                    # never skip the final position: its logits seed decode
-                    skip_pages = min(n_hit, (total - 1) // self.page_size)
-                    n_skip = skip_pages * self.page_size
-                    first_len = min(total, n_skip + self.chunk_size)
-                    need_total = min(
-                        total + (0 if req.max_tokens <= 1 else 1),
-                        self.max_seq)
-                    # structural sizing check (drain limit: everything else
-                    # eventually finishes and frees its pages, but the
-                    # request's own holdings — shared hits included — still
-                    # occupy capacity). Two ways a request can never
-                    # complete, each a raise-now instead of stall-forever:
-                    # absolute capacity must cover prompt + the first
-                    # decode page, and — when any prefill page must be
-                    # freshly allocated — the whole prompt footprint must
-                    # fit the admission side, which cannot touch the decode
-                    # headroom reserve (the decode page itself may).
-                    usable = self.pool.num_pages - 1
-                    prefill_pages = self.pool.num_pages_for(total)
-                    if (self.pool.num_pages_for(need_total) > usable
-                            or (prefill_pages - n_hit > 0 and prefill_pages
-                                > usable - self.pool.reserve)):
-                        raise PoolExhausted(
-                            f"KV pool ({usable} pages, {self.pool.reserve} "
-                            f"reserved) too small for request {req.uid} "
-                            f"({prefill_pages} prompt pages, "
-                            f"{n_hit} prefix-shared)")
-                    if not self.pool.can_admit(first_len, keys):
-                        in_flight = any(
-                            self.slots[v] is not None or v in sched.tasks
-                            for v in range(self.n_slots))
-                        if not in_flight:
-                            raise PoolExhausted(
-                                f"KV pool cannot admit request {req.uid} "
-                                f"with nothing in flight to free pages")
-                        # pool-aware policy: evict the longest-idle stalled
-                        # task and re-evaluate with the (possibly new) queue
-                        # head; with no stalled victim, defer — something
-                        # in flight is progressing and will free pages
-                        if not self._evict_longest_idle():
-                            return
-                        continue
-                    try:
-                        pages, n_shared = self.pool.admit(s, first_len, keys,
-                                                          register=False)
-                        # recomputed positions may land in shared pages when
-                        # the skip cap pulled below the hit run: COW them
-                        copies = self.pool.prepare_write(s, n_skip, total)
-                    except PoolExhausted:
-                        self.pool.free_slot(s)
+                with span("engine.admit", self.stats.spans, uid=req.uid):
+                    if not self._admit_task(req, s):
                         return
-                    req.pages_shared = n_shared
-                    self._reset_fresh_scales(list(pages[n_shared:])
-                                             + [d for _, d in copies])
-                    self._dispatch_copies(copies)
-                    self._update_cache_stats()
-                sched.waiting.pop(0)
-                t0 = time.perf_counter()
-                req.queue_s = t0 - req.t_submit
-                self.stats.queue_s.append(req.queue_s)
-                batch = {"tokens": jnp.asarray(req.prompt[None, :])}
-                if n_prefix:
-                    if n_skip < n_prefix:
-                        prefix = self._vision(self.params,
-                                              jnp.asarray(req.patches[None]))
-                        prefix.block_until_ready()
-                        t1 = time.perf_counter()
-                        self.stats.vision_time += t1 - t0
-                        t0 = t1
-                    else:
-                        # the whole vision prefix is prefix-cache shared:
-                        # its KV already sits in pool pages, so the tower
-                        # run itself is skipped (chunks never read these
-                        # embedding rows)
-                        prefix = jnp.zeros((1, n_prefix, self.cfg.d_model),
-                                           self.cache_dtype)
-                    batch["prefix"] = prefix
-                embeds = M.embed_prompt(self.cfg, self.opts, self.params,
-                                        batch)
-                cache1 = None if self.paged else self._fresh_cache1()
-                req.prefill_skipped = n_skip
-                self.stats.prefill_skipped += n_skip
-                sched.start_task(PrefillTask(req=req, slot=s, total=total,
-                                             n_skip=n_skip, embeds=embeds,
-                                             cache1=cache1, prefix_keys=keys,
-                                             t_start=t0))
-                self._last_active[s] = t0
+
+    def _admit_task(self, req: Request, s: int) -> bool:
+        """One admission attempt of the waiting queue's head ``req`` into
+        slot ``s`` as a prefill task; False when admission has to stop
+        for this tick (the pool defers it)."""
+        sched = self.scheduler
+        n_prefix = (self.cfg.vision.num_tokens
+                    if req.patches is not None and self._vision
+                    else 0)
+        total = n_prefix + len(req.prompt)
+        if total > self.max_seq:
+            raise ValueError(
+                f"request {req.uid}: prompt ({total} positions) "
+                f"exceeds max_seq {self.max_seq}")
+        n_skip = 0
+        keys: List[bytes] = []
+        if self.paged:
+            keys = self._prefix_page_keys(req, n_prefix)
+            n_hit = self.pool.match_prefix(keys)
+            # never skip the final position: its logits seed decode
+            skip_pages = min(n_hit, (total - 1) // self.page_size)
+            n_skip = skip_pages * self.page_size
+            first_len = min(total, n_skip + self.chunk_size)
+            need_total = min(
+                total + (0 if req.max_tokens <= 1 else 1),
+                self.max_seq)
+            # structural sizing check (drain limit: everything else
+            # eventually finishes and frees its pages, but the
+            # request's own holdings — shared hits included — still
+            # occupy capacity). Two ways a request can never
+            # complete, each a raise-now instead of stall-forever:
+            # absolute capacity must cover prompt + the first
+            # decode page, and — when any prefill page must be
+            # freshly allocated — the whole prompt footprint must
+            # fit the admission side, which cannot touch the decode
+            # headroom reserve (the decode page itself may).
+            usable = self.pool.num_pages - 1
+            prefill_pages = self.pool.num_pages_for(total)
+            if (self.pool.num_pages_for(need_total) > usable
+                    or (prefill_pages - n_hit > 0 and prefill_pages
+                        > usable - self.pool.reserve)):
+                raise PoolExhausted(
+                    f"KV pool ({usable} pages, {self.pool.reserve} "
+                    f"reserved) too small for request {req.uid} "
+                    f"({prefill_pages} prompt pages, "
+                    f"{n_hit} prefix-shared)")
+            if not self.pool.can_admit(first_len, keys):
+                in_flight = any(
+                    self.slots[v] is not None or v in sched.tasks
+                    for v in range(self.n_slots))
+                if not in_flight:
+                    raise PoolExhausted(
+                        f"KV pool cannot admit request {req.uid} "
+                        f"with nothing in flight to free pages")
+                # pool-aware policy: evict the longest-idle stalled
+                # task and re-evaluate with the (possibly new) queue
+                # head; with no stalled victim, defer — something
+                # in flight is progressing and will free pages
+                if not self._evict_longest_idle():
+                    return False
+                return True
+            try:
+                pages, n_shared = self.pool.admit(s, first_len, keys,
+                                                  register=False)
+                # recomputed positions may land in shared pages when
+                # the skip cap pulled below the hit run: COW them
+                copies = self.pool.prepare_write(s, n_skip, total)
+            except PoolExhausted:
+                self.pool.free_slot(s)
+                return False
+            req.pages_shared = n_shared
+            self._reset_fresh_scales(list(pages[n_shared:])
+                                     + [d for _, d in copies])
+            self._dispatch_copies(copies)
+            self._update_cache_stats()
+        sched.waiting.pop(0)
+        rec = self.stats.spans
+        req.queue_s = time.perf_counter() - req.t_submit
+        self.stats.queue_s.append(req.queue_s)
+        batch = {"tokens": jnp.asarray(req.prompt[None, :])}
+        if n_prefix:
+            if n_skip < n_prefix:
+                with span("engine.vision", rec, uid=req.uid):
+                    prefix = self._vision(self.params,
+                                          jnp.asarray(req.patches[None]))
+                    prefix.block_until_ready()
+            else:
+                # the whole vision prefix is prefix-cache shared: its KV
+                # already sits in pool pages, so the tower run itself is
+                # skipped (chunks never read these embedding rows)
+                prefix = jnp.zeros((1, n_prefix, self.cfg.d_model),
+                                   self.cache_dtype)
+            batch["prefix"] = prefix
+        t0 = time.perf_counter()
+        with span("engine.embed", rec, uid=req.uid):
+            embeds = M.embed_prompt(self.cfg, self.opts, self.params, batch)
+        cache1 = None if self.paged else self._fresh_cache1()
+        req.prefill_skipped = n_skip
+        self.stats.prefill_skipped += n_skip
+        sched.start_task(PrefillTask(req=req, slot=s, total=total,
+                                     n_skip=n_skip, embeds=embeds,
+                                     cache1=cache1, prefix_keys=keys,
+                                     t_start=t0))
+        self._last_active[s] = t0
+        return True
 
     def _run_chunk(self, cp: ChunkPlan):
         """Execute one planned prefill chunk: grow pages to cover it (paged),
@@ -1733,7 +1804,6 @@ class ServingEngine:
         jitted positioned prefill, and — on the final chunk — sample the
         request's first token and flip the slot to decoding."""
         task, s = cp.task, cp.task.slot
-        t0 = time.perf_counter()
         if self.paged:
             end = cp.start + cp.n_tok
             held0 = set(self.pool.slot_pages[s])
@@ -1794,7 +1864,6 @@ class ServingEngine:
         self._last_active[s] = time.perf_counter()
         if task.pos >= task.total:
             self._finish_prefill(task, logits)
-        self.stats.prefill_time += time.perf_counter() - t0
 
     def _finish_prefill(self, task: PrefillTask, logits):
         """Last chunk done: sample the first token (TTFT boundary) from the
@@ -1803,8 +1872,8 @@ class ServingEngine:
         the slot to the decode stage."""
         req, s = task.req, task.slot
         pos = task.total
-        tok = int(self._sample_host(logits)[0])
-        self.stats.prefill_syncs += 1
+        with span("engine.first_token", self.stats.spans, uid=req.uid):
+            tok = int(self._sample_host(logits)[0])
         now = time.perf_counter()
         req.t_prefill = now
         req.ttft_s = now - req.t_submit
@@ -1872,27 +1941,32 @@ class ServingEngine:
         sched = self.scheduler
         self._admit_chunked()
         n_active = sum(r is not None for r in self.slots)
-        slo = None
-        if self._slo is not None:
-            # the deadline check: remaining work + slack per realtime
-            # decoding slot, plus whether realtime prefill is still in the
-            # pipe, against the measured tick EWMA (see SLOController)
-            rt_decode = [(int(self.budget[s]), req_deadline(self.slots[s]))
-                         for s in range(self.n_slots)
-                         if self.slots[s] is not None
-                         and is_realtime(self.slots[s])]
-            rt_prefill = (any(is_realtime(t.req)
-                              for t in sched.tasks.values())
-                          or any(is_realtime(r) for r in sched.waiting))
-            slo = self._slo.plan(t_tick, self.stats.tick_ewma_s,
-                                 rt_decode, rt_prefill)
-        plan = sched.plan_tick(n_active, self.tick_tokens, slo=slo)
+        with span("engine.plan", self.stats.spans):
+            slo = None
+            if self._slo is not None:
+                # the deadline check: remaining work + slack per
+                # realtime decoding slot, plus whether realtime prefill is
+                # still in the pipe, against the measured tick EWMA (see
+                # SLOController)
+                rt_decode = [(int(self.budget[s]),
+                              req_deadline(self.slots[s]))
+                             for s in range(self.n_slots)
+                             if self.slots[s] is not None
+                             and is_realtime(self.slots[s])]
+                rt_prefill = (any(is_realtime(t.req)
+                                  for t in sched.tasks.values())
+                              or any(is_realtime(r) for r in sched.waiting))
+                slo = self._slo.plan(t_tick, self.stats.tick_ewma_s,
+                                     rt_decode, rt_prefill)
+            plan = sched.plan_tick(n_active, self.tick_tokens, slo=slo)
         for cp in plan.chunks:
             if sched.tasks.get(cp.task.slot) is not cp.task:
                 continue    # finished or preempted earlier this tick
             if cp.task.pos != cp.start:
                 continue    # an earlier chunk of this task stalled
-            self._run_chunk(cp)
+            with span("engine.chunk", self.stats.spans, uid=cp.task.req.uid,
+                      start=cp.start):
+                self._run_chunk(cp)
         emitted = 0
         if n_active:
             emitted = self._decode_tick(plan.decode_steps)

@@ -46,6 +46,11 @@ boundaries, on one driver coroutine per replica::
                                               per live stream, close
                                               finished ones
 
+    Each step is a span (``serving.tracing``) in the replica's recorder,
+    ``EngineStats.spans``: ``frontend.submit`` (routing and staging, with
+    the request's uid), ``frontend.drain``, ``frontend.pump`` and
+    ``frontend.park`` (waiting on the wake event with nothing to serve).
+
     The engine is only ever touched between its own ticks by its own
     driver, so no engine state needs locking; the staging deques and the
     per-stream asyncio queues are the only cross-context structures.
@@ -68,6 +73,7 @@ import numpy as np
 
 from repro.serving.engine import Request, ServingEngine, prefix_page_keys
 from repro.serving.scheduler import BEST_EFFORT, REALTIME
+from repro.serving.tracing import span
 
 
 class Backpressure(RuntimeError):
@@ -403,16 +409,19 @@ class AsyncFrontend:
         engine's SLO controller when it runs one (``slo_hz > 0``)."""
         if not self._running:
             raise RuntimeError("AsyncFrontend not started")
-        i = self._route(prompt, patches, priority)
-        uid, self._uid = self._uid, self._uid + 1
-        req = Request(uid=uid, prompt=np.asarray(prompt, np.int32),
-                      max_tokens=max_tokens, patches=patches,
-                      priority=priority, deadline_s=deadline_s)
-        stream = TokenStream(uid, req, i)
-        stream._frontend = self
-        self._staged[i].append(stream)
-        self.stats.submitted += 1
-        self._wake[i].set()
+        uid = self._uid
+        with span("frontend.submit", uid=uid) as sp:
+            i = self._route(prompt, patches, priority)
+            sp.rec = self.engines[i].stats.spans
+            self._uid += 1
+            req = Request(uid=uid, prompt=np.asarray(prompt, np.int32),
+                          max_tokens=max_tokens, patches=patches,
+                          priority=priority, deadline_s=deadline_s)
+            stream = TokenStream(uid, req, i)
+            stream._frontend = self
+            self._staged[i].append(stream)
+            self.stats.submitted += 1
+            self._wake[i].set()
         return stream
 
     def cancel(self, stream: TokenStream):
@@ -482,9 +491,11 @@ class AsyncFrontend:
         """Replica ``i``'s tick loop: drain control -> tick -> pump, or
         park on the wake event when there is nothing to do."""
         eng = self.engines[i]
+        rec = eng.stats.spans
         loop = asyncio.get_event_loop()
         while self._running:
-            self._drain_control(i)
+            with span("frontend.drain", rec):
+                self._drain_control(i)
             if not eng.pending:
                 if not self._staged[i] and not self._cancels[i]:
                     self._wake[i].clear()
@@ -492,7 +503,8 @@ class AsyncFrontend:
                     # the clear must not be lost (set-then-clear race)
                     if not self._staged[i] and not self._cancels[i] \
                             and self._running:
-                        await self._wake[i].wait()
+                        with span("frontend.park", rec):
+                            await self._wake[i].wait()
                 continue
             t0 = time.perf_counter()
             if self.offload_ticks:
@@ -502,4 +514,5 @@ class AsyncFrontend:
                 await asyncio.sleep(0)      # let submit/cancel interleave
             self._tick_ewma[i] = (0.8 * self._tick_ewma[i]
                                   + 0.2 * (time.perf_counter() - t0))
-            self._pump(i)
+            with span("frontend.pump", rec):
+                self._pump(i)

@@ -35,6 +35,7 @@ from repro.models import layers
 from repro.models import model as M
 from repro.models.layers import ModelOptions
 from repro.models.params import _init_tree
+from repro.serving import engine as E
 from repro.training import TrainConfig, init_train_state, make_train_step
 
 CFG = get_config("molmoact-7b")
@@ -195,3 +196,51 @@ def test_cut_depth_decode_step_fits_one_chip(one_chip, monkeypatch):
     ma = compiled.memory_analysis()
     used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
     assert used < CHIP_BYTES, f"{used / 1e9:.2f} GB does not fit one chip"
+
+
+def _stage_program(stage, sharding):
+    """The engine's ``stage`` program (as ``ServingEngine`` binds it) for a
+    paged, chunked bf16 engine at the registry's reduced molmoact-7b size,
+    lowered and compiled for ``sharding``."""
+    cfg = get_config("molmoact-7b").reduced()
+    opts = ModelOptions(remat=False)
+    slots, max_seq, page = 2, 64, 8
+    npg = max_seq // page
+    params = _abstract_params(cfg, BF16, sharding)
+    caches = _abstract(jax.eval_shape(lambda: M.init_caches(
+        cfg, slots, max_seq, BF16, opts, paged=True,
+        num_pages=1 + slots * npg, page_size=page)), sharding)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    if stage == "tick":
+        prog = E._jit_tick(cfg, opts, 4, 1, 0.0, 0, True)
+        lowered = prog.lower(params, arg((slots, 1)), caches, arg((slots,)),
+                             arg((slots,)), arg((slots,), jnp.bool_),
+                             arg((2,), jnp.uint32), arg(()),
+                             arg((slots, npg)))
+    elif stage == "chunk":
+        prog = E._jit_prefill_chunk(cfg, opts, True)
+        lowered = prog.lower(params, arg((1, page, cfg.d_model), BF16),
+                             caches, arg(()), arg(()), arg((1, npg)),
+                             2 * page)
+    else:
+        prog = E._jit_vision(cfg, opts)
+        lowered = prog.lower(params, arg(
+            (1, cfg.vision.num_tokens, cfg.vision.embed_dim), BF16))
+    return lowered.compile().as_text()
+
+
+# what bench/harness/programs.py reads in a chip trace: the module names,
+# the two paged kernels' custom-call names, and the vision weights' op names
+@pytest.mark.parametrize("stage,marker", [
+    ("tick", "%paged_decode_attention."),
+    ("chunk", "%paged_chunk_prefill_attention."),
+    ("vision", "p__vision__")])
+def test_stage_programs_carry_their_names(one_chip, monkeypatch, stage,
+                                          marker):
+    monkeypatch.setattr(layers, "on_tpu", lambda: True)
+    text = _stage_program(stage, one_chip)
+    assert text.startswith(f"HloModule jit_serve_{stage},"), text[:80]
+    assert marker in text
+    assert stage == "vision" or "tpu_custom_call" in text
