@@ -3,8 +3,8 @@
 Same banded chunk attention as ``chunk_prefill.py`` — the online-softmax
 body is literally shared (``decode_attention.flash_block``) — but the KV
 cache lives in a shared head-major page pool ``[num_pages, K, page_size,
-h]`` addressed through a per-slot page table, exactly like the paged
-flash-decode kernel (``decode_attention/paged.py``). The page table and the
+h]`` addressed through a per-slot page table, like the paged flash-decode
+kernel (``decode_attention/paged.py``). The page table and the
 per-slot chunk start positions arrive as scalar-prefetch operands, so the
 *index map itself* gathers KV pages: grid cell ``(b, head, p)`` DMAs the
 ``(page_size, h)`` tile of physical page ``page_table[b, p]`` from HBM — the
@@ -39,7 +39,15 @@ from repro.configs.base import GLOBAL_WINDOW
 from repro.kernels.decode_attention.decode_attention import (block_live,
                                                              flash_block,
                                                              softmax_scratch)
-from repro.kernels.decode_attention.paged import head_row, token_scales
+from repro.kernels.decode_attention.paged import token_scales
+
+
+def head_row(s_ref, kh):
+    """Row ``kh`` of a ``[1, K, ps]`` scale block as a ``[1, ps]`` f32 row
+    (a masked sublane sum: the head is a grid index, not a static slice)."""
+    tile = s_ref[0]                                       # [K, ps]
+    sel = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0) == kh
+    return jnp.sum(jnp.where(sel, tile, 0.0), axis=0, keepdims=True)
 
 
 def _paged_kernel(pt_ref, idx_ref, q_ref, k_ref, v_ref, o_ref,

@@ -17,12 +17,14 @@ paged decode kernel does on GPU, re-expressed for the TPU's sequential grid.
 ``index`` may be a scalar or a per-slot ``[B]`` vector (continuous
 batching): each batch row masks and early-exits against its own position.
 
-``flash_block`` is the single online-softmax body shared by every decode and
-chunk-prefill kernel (dense and paged, ``chunk_prefill/``): the kernels
-differ only in how the KV block for a grid cell is located (contiguous rows
-vs page-table gather) and in where their query rows sit (decode: G heads at
-one position; chunk: S positions of one head), so the numerically-sensitive
-part lives in exactly one place.
+``softmax_update`` is the single online-softmax arithmetic shared by every
+decode and chunk-prefill kernel (dense and paged, ``chunk_prefill/``), so
+the numerically-sensitive part lives in exactly one place. ``flash_block``
+runs it once per grid cell, for the kernels that differ only in how a grid
+cell's KV block is located (contiguous rows vs page-table index map) and in
+where their query rows sit (decode: G heads at one position; chunk: S
+positions of one head). The paged decode kernel (``paged.py``) runs it once
+per page of a block of pages it gathered itself, batched over the KV heads.
 """
 from __future__ import annotations
 
@@ -49,6 +51,67 @@ def block_live(index, last, k_start, bk: int, window: int):
     if window != GLOBAL_WINDOW:
         live = jnp.logical_and(live, (index - (k_start + bk - 1)) < window)
     return live
+
+
+def live_pages(index, ps: int, window: int, xp=jnp):
+    """First live page and live page count of a query at ``index``: the
+    pages ``p`` for which ``block_live(index, index, p * ps, ps, window)``
+    holds, a contiguous run that always includes ``index``'s own page.
+    ``xp`` is ``jnp`` inside kernels and ``np`` for host-side counts."""
+    first = 0
+    if window != GLOBAL_WINDOW:
+        first = xp.maximum(index - window + 1, 0) // ps
+    return first, index // ps + 1 - first
+
+
+def softmax_update(q, k, v, m, l, acc, *, index, last, k_start,
+                   window: int, row_step: int, k_scale=None, v_scale=None):
+    """The f32 arithmetic of one key block of the online softmax, on values.
+    ``q`` [..., R, h] and ``k``/``v`` [..., bk, h] share their leading
+    (batch) axes: none in ``flash_block``, the KV heads in the paged decode
+    kernel. Key lane j sits at absolute position ``k_start + j``; query row
+    r at ``index + row_step * r``, the youngest at ``last``. ``m``/``l``
+    [..., R, 1] and ``acc`` [..., R, h] are the running max, sum and
+    unnormalized output; returns their updates. ``k_scale``/``v_scale``
+    ([..., 1, bk] f32) dequantize int8/fp8 codes as ``flash_block`` says.
+
+    A block whose every lane is masked for a row is an exact no-op for it,
+    whatever the block holds: scores are replaced (not multiplied) by the
+    mask and V is zeroed on lanes past ``last``, so stale or NaN-padded
+    rows cannot reach the accumulator."""
+    nb = q.ndim - 2
+    batch = tuple(range(nb))
+    q = q.astype(jnp.float32)                          # [..., R, h]
+    k = k.astype(jnp.float32)                          # [..., bk, h]
+    v = v.astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, (((nb + 1,), (nb + 1,)), (batch, batch)),
+                            preferred_element_type=jnp.float32)
+    s = s * (1.0 / np.sqrt(q.shape[-1]))               # [..., R, bk]
+    if k_scale is not None:
+        s = s * k_scale
+    q_pos = index + row_step * jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, nb)
+    kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, nb + 1)
+    mask = kpos <= q_pos
+    if window != GLOBAL_WINDOW:
+        mask &= (q_pos - kpos) < window
+    s = jnp.where(mask, s, NEG_INF)
+    # lanes dead for every row may be out-of-bounds tail lanes —
+    # NaN-padded in interpret mode, undefined on TPU — and 0 * NaN
+    # would poison the accumulator
+    bk = k.shape[-2]
+    kcol = k_start + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+    v = jnp.where(kcol <= last, v, 0.0)
+    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new) * mask
+    corr = jnp.exp(m - m_new)
+    l = l * corr + p.sum(axis=-1, keepdims=True)
+    if v_scale is not None:
+        p = p * v_scale
+    acc = acc * corr + jax.lax.dot_general(
+        p, v, (((nb + 1,), (nb,)), (batch, batch)),
+        preferred_element_type=jnp.float32)
+    return m_new, l, acc
 
 
 def flash_block(index, ik, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
@@ -89,49 +152,33 @@ def flash_block(index, ik, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(block_live(index, last, k_start, bk, window))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)            # [R, h]
-        k = k_ref[0, 0].astype(jnp.float32)            # [bk, h]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * (1.0 / np.sqrt(q.shape[-1]))           # [R, bk]
-        if k_scale is not None:
-            s = s * k_scale
-        q_pos = index + row_step * jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos <= q_pos
-        if window != GLOBAL_WINDOW:
-            mask &= (q_pos - kpos) < window
-        s = jnp.where(mask, s, NEG_INF)
-        # lanes dead for every row may be out-of-bounds tail lanes —
-        # NaN-padded in interpret mode, undefined on TPU — and 0 * NaN
-        # would poison the accumulator
-        kcol = k_start + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
-        v = jnp.where(kcol <= last, v, 0.0)
-        m_prev = m_scr[...]                            # [R, 1]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new) * mask
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
-        if v_scale is not None:
-            p = p * v_scale
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        m, l, acc = softmax_update(
+            q_ref[0, 0], k_ref[0, 0], v_ref[0, 0],
+            m_scr[...], l_scr[...], acc_scr[...], index=index, last=last,
+            k_start=k_start, window=window, row_step=row_step,
+            k_scale=k_scale, v_scale=v_scale)
+        m_scr[...] = m
+        l_scr[...] = l
+        acc_scr[...] = acc
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = softmax_result(l_scr[...], acc_scr[...], o_ref.dtype)
 
 
-def softmax_scratch(rows: int, h: int):
+def softmax_result(l, acc, dtype):
+    """The normalized output of a finished online softmax."""
+    return (acc / jnp.maximum(l, 1e-30)).astype(dtype)
+
+
+def softmax_scratch(rows, h: int):
     """VMEM scratch for ``flash_block``: running max, running sum, and the
-    unnormalized output accumulator of R query rows."""
-    return [pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, h), jnp.float32)]
+    unnormalized output accumulator of R query rows (``rows`` R, or a
+    tuple of leading axes ending in R)."""
+    rows = rows if isinstance(rows, tuple) else (rows,)
+    return [pltpu.VMEM(rows + (1,), jnp.float32),
+            pltpu.VMEM(rows + (1,), jnp.float32),
+            pltpu.VMEM(rows + (h,), jnp.float32)]
 
 
 def _kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,

@@ -34,8 +34,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, index, *,
     [num_pages, K, page_size, h]; page_table [B, npg] int32; index scalar or
     per-slot [B] vector of current positions. For quantized (int8/fp8)
     pools pass the sibling scales ``k_scales``/``v_scales`` ([num_pages, K]
-    or [num_pages, K, page_size] f32) — the kernel gathers them through the
-    same page-table index map and dequantizes inside the VMEM tile."""
+    or [num_pages, K, page_size] f32) — the kernel copies each page's scale
+    rows beside its codes and dequantizes inside the VMEM tile."""
     return paged_decode_attention_kernel(q, k_pages, v_pages, page_table,
                                          index, k_scales=k_scales,
                                          v_scales=v_scales, window=window,

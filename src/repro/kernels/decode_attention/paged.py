@@ -1,32 +1,52 @@
 """Paged flash-decode Pallas TPU kernel (bf16 or quantized int8/fp8 pages).
 
-Same bandwidth-tuned single-token GQA attention as ``decode_attention`` —
-the online-softmax body is literally shared (``flash_block``) — but the KV
-cache lives in a shared head-major page pool ``[num_pages, K, page_size, h]``
-instead of a dense per-slot ``[B, K, S, h]`` buffer. Each slot's logical
-sequence is described by a row of the page table: logical positions
-``[p*page_size, (p+1)*page_size)`` live in physical page ``page_table[b, p]``.
+Single-token GQA attention against a shared page-major KV pool
+``[num_pages, K, page_size, h]``: a slot's logical positions
+``[p*page_size, (p+1)*page_size)`` live in physical page
+``page_table[b, p]``, and all K heads of one page are one contiguous run of
+HBM.
 
-The page table and the per-slot positions arrive as scalar-prefetch
-operands, so the *index map itself* gathers KV blocks through the page
-table: grid cell ``(b, kv_head, p)`` DMAs the ``(page_size, h)`` tile of
-physical page ``page_table[b, p]`` for that head from HBM. Fully-masked
-pages (past a slot's position, or entirely older than its sliding window)
-are remapped to the null page so their DMA is never issued, and their
-compute is skipped by ``pl.when`` — vLLM's paged attention early-exit,
-re-expressed for the TPU's sequential grid.
+Grid and DMA pipeline. The grid has one step per slot, and a step covers
+all K heads. The K and V pools stay in HBM (``memory_space=pl.ANY``); the
+kernel gathers a slot's pages itself, a block of ``ppb`` pages at a time,
+with one ``make_async_copy`` per page of its whole ``(K, page_size, h)``
+slab into a double-buffered VMEM scratch ``(2, ppb, K, page_size, h)``.
+Block j+1's copies start before block j is computed, and the last block of
+a slot starts the next slot's first block, so the DMA engine runs on across
+grid steps (which is why the grid axis is sequential, "arbitrary").
+
+Live pages only. From the scalar-prefetched position and the sliding
+window, ``live_pages`` (the ``block_live`` rule at one page) gives the
+slot's first live page and live page count. The block loop's trip count is
+dynamic, so dead pages past the position or older than the window cost no
+grid step, and no page past the position is ever copied: the last block
+copies only its live pages. The kernel reads every page that holds a live
+position, whole, and nothing else.
+
+``ppb`` comes from the shapes alone (``pages_per_block``): about
+``BLOCK_KEYS`` = 256 key positions, no more than the table's pages, at most
+``MAX_BLOCK_PAGES`` (the page loop is unrolled), and the four page buffers
+(K and V, double-buffered) within ``KV_VMEM_BUDGET``. At molmoact-7b's
+shapes (page 32, 4 KV heads of 128, bf16) that is 8 pages, 2 x 2 x 256 KB.
+
+Numerics. Inside a block the online softmax is updated once per page, in
+page order, by ``softmax_update`` (the arithmetic ``flash_block`` runs),
+batched over the K heads: every query head of a group shares its group's
+tile. So the absolute key-block partition stays at ``page_size``, and the
+kernel agrees bit for bit with the dense decode kernel at
+``bk = page_size`` and with the chunk-prefill kernels at
+``prefill_band = page_size``. Buffer pages past a short last block hold
+stale data; their update is ``softmax_update``'s exact no-op for
+fully-masked blocks.
 
 Quantized pools (``k_scales``/``v_scales`` given) stream 1-byte codes plus
-one f32 scale array per pool — ``[num_pages, K]`` (per-(page, head)) or
-``[num_pages, K, page_size]`` (per-token), dispatched on ndim. The kernel
-reads per-token rows only: a per-(page, head) scale is broadcast over its
-page's rows in the wrapper. A grid cell takes the page's ``(K, page_size)``
-scale tile (a whole-array block on the tiled axes, gathered through the
-same page-table index map), picks its head's row, and dequantizes in the
-score and probability columns (see ``flash_block``), so HBM traffic per
-token drops to ~1 byte per cache element while the online softmax stays
-fp32. int8 pages need ``page_size`` to be a multiple of 32 on the TPU (the
-int8 sublane tile).
+one f32 scale array per pool, ``[num_pages, K]`` (per-(page, head)) or
+``[num_pages, K, page_size]`` (per-token). The kernel reads per-token rows:
+a per-(page, head) scale is broadcast over its page's rows in the wrapper.
+Each page's ``(K, page_size)`` scale rows are copied beside its codes, and
+dequantize the score and probability columns (see ``flash_block``). int8
+pages need ``page_size`` to be a multiple of 32 on the TPU (the int8
+sublane tile).
 
 Page 0 is the pool's reserved null page: padding entries in the table point
 at it and its contribution is always masked.
@@ -41,9 +61,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.base import GLOBAL_WINDOW
-from repro.kernels.decode_attention.decode_attention import (block_live,
-                                                             flash_block,
-                                                             softmax_scratch)
+from repro.kernels.decode_attention.decode_attention import (
+    NEG_INF, block_live, live_pages, softmax_result, softmax_scratch,
+    softmax_update)
+
+LANES = 128                     # the TPU's lane tile
+BLOCK_KEYS = 256                # key positions a page block aims for
+MAX_BLOCK_PAGES = 16            # the page loop is unrolled
+KV_VMEM_BUDGET = 4 * 1024 * 1024  # K and V page buffers, double-buffered
 
 
 def token_scales(scales, page_size: int):
@@ -56,29 +81,189 @@ def token_scales(scales, page_size: int):
     return jnp.broadcast_to(scales[:, :, None], scales.shape + (page_size,))
 
 
-def head_row(s_ref, kh):
-    """Row ``kh`` of a ``[1, K, ps]`` scale block as a ``[1, ps]`` f32 row
-    (a masked sublane sum: the head is a grid index, not a static slice)."""
-    tile = s_ref[0]                                       # [K, ps]
-    sel = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0) == kh
-    return jnp.sum(jnp.where(sel, tile, 0.0), axis=0, keepdims=True)
+def pages_per_block(npg: int, ps: int, K: int, h: int, itemsize: int) -> int:
+    """Pages a grid step gathers per block, from the shapes alone."""
+    page_bytes = 2 * 2 * K * ps * h * itemsize
+    return max(1, min(npg, BLOCK_KEYS // ps, MAX_BLOCK_PAGES,
+                      KV_VMEM_BUDGET // page_bytes))
 
 
-def _paged_kernel(pt_ref, idx_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, ps: int, npg: int, window: int):
-    flash_block(idx_ref[pl.program_id(0)], pl.program_id(2),
-                q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                bk=ps, nk=npg, window=window, row_step=0)
+def _paged_kernel(pt_ref, idx_ref, q_ref, *refs, ps: int, ppb: int,
+                  window: int, n_streams: int):
+    # refs: the pools in HBM (K, V[, K scales, V scales]), the output
+    # block, their VMEM page buffers, the DMA semaphores, and the SMEM
+    # index of the buffer that holds the next block to compute
+    hbm = refs[:n_streams]
+    o_ref = refs[n_streams]
+    bufs = refs[n_streams + 1:2 * n_streams + 1]
+    sems, cur_ref = refs[2 * n_streams + 1:]
+    b, nb = pl.program_id(0), pl.num_programs(0)
+
+    def copies(s, j, buf, p):
+        # the copies of page p of slot s's j-th live block into buffer buf
+        first, _ = live_pages(idx_ref[s], ps, window)
+        page = pt_ref[s, first + j * ppb + p]
+        return [pltpu.make_async_copy(src.at[page], dst.at[buf, p],
+                                      sems.at[i, buf])
+                for i, (src, dst) in enumerate(zip(hbm, bufs))]
+
+    def block_pages(s, j):
+        _, n = live_pages(idx_ref[s], ps, window)
+        return jnp.minimum(ppb, n - j * ppb)
+
+    def for_block(s, j, buf, op):
+        n = block_pages(s, j)
+        for p in range(ppb):
+            @pl.when(p < n)
+            def _():
+                for c in copies(s, j, buf, p):
+                    getattr(c, op)()
+
+    @pl.when(b == 0)
+    def _prime():
+        for scale_buf in bufs[2:]:
+            # stale scale rows meet p == 0 in softmax_update: keep them
+            # finite, or 0 * NaN would reach the accumulator
+            scale_buf[...] = jnp.zeros_like(scale_buf)
+        cur_ref[0] = 0
+        for_block(0, 0, 0, "start")
+
+    index = idx_ref[b]
+    first, n = live_pages(index, ps, window)
+    n_blocks = (n + ppb - 1) // ppb
+    q = q_ref[0]                                       # [K, G, h]
+    K, G, h = q.shape
+
+    def block(j, carry):
+        buf = cur_ref[0]
+        nxt = 1 - buf
+
+        @pl.when(j + 1 < n_blocks)
+        def _prefetch_own():
+            for_block(b, j + 1, nxt, "start")
+
+        @pl.when(jnp.logical_and(j + 1 == n_blocks, b + 1 < nb))
+        def _prefetch_next_slot():
+            for_block(b + 1, 0, nxt, "start")
+
+        for_block(b, j, buf, "wait")
+        m, l, acc = carry
+        for p in range(ppb):
+            scales = ([jnp.expand_dims(x[buf, p, :, :ps], 1)
+                       for x in bufs[2:]] or [None] * 2)
+            m, l, acc = softmax_update(
+                q, bufs[0][buf, p], bufs[1][buf, p], m, l, acc,
+                index=index, last=index,
+                k_start=(first + j * ppb + p) * ps, window=window,
+                row_step=0, k_scale=scales[0], v_scale=scales[1])
+        cur_ref[0] = nxt
+        return m, l, acc
+
+    init = (jnp.full((K, G, 1), NEG_INF, jnp.float32),
+            jnp.zeros((K, G, 1), jnp.float32),
+            jnp.zeros((K, G, h), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, init)
+    o_ref[0] = softmax_result(l, acc, o_ref.dtype)
 
 
-def _paged_quant_kernel(pt_ref, idx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                        o_ref, m_scr, l_scr, acc_scr, *, ps: int, npg: int,
-                        window: int):
-    kh = pl.program_id(1)
-    flash_block(idx_ref[pl.program_id(0)], pl.program_id(2),
-                q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                bk=ps, nk=npg, window=window, row_step=0,
-                k_scale=head_row(ks_ref, kh), v_scale=head_row(vs_ref, kh))
+def _page_grid_kernel(pt_ref, idx_ref, q_ref, k_ref, v_ref, *refs, ps: int,
+                      npg: int, window: int):
+    # one grid step per (slot, page), all K heads: the narrow-head path
+    *scale_refs, o_ref, m_scr, l_scr, acc_scr = refs
+    index, ip = idx_ref[pl.program_id(0)], pl.program_id(1)
+
+    @pl.when(ip == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(block_live(index, index, ip * ps, ps, window))
+    def _compute():
+        scales = [jnp.expand_dims(x[0], 1) for x in scale_refs] or [None] * 2
+        m, l, acc = softmax_update(
+            q_ref[0], k_ref[0], v_ref[0], m_scr[...], l_scr[...],
+            acc_scr[...], index=index, last=index, k_start=ip * ps,
+            window=window, row_step=0, k_scale=scales[0],
+            v_scale=scales[1])
+        m_scr[...] = m
+        l_scr[...] = l
+        acc_scr[...] = acc
+
+    @pl.when(ip == npg - 1)
+    def _finalize():
+        o_ref[0] = softmax_result(l_scr[...], acc_scr[...], o_ref.dtype)
+
+
+def _page_grid_call(qg, pools, pt, idx, *, window: int, interpret: bool):
+    """The paged decode for heads narrower than a 128-lane tile, which
+    Mosaic cannot slice a page of out of an HBM pool: the BlockSpec index
+    map gathers one whole ``(K, page_size, h)`` page per grid step through
+    the page table, and dead pages are remapped to the null page so their
+    copy is elided (repeated block indices are not fetched again)."""
+    B, K, G, h = qg.shape
+    ps, npg = pools[0].shape[2], pt.shape[1]
+
+    def page(b, ip, pt_ref, idx_ref):
+        i = idx_ref[b]
+        return jnp.where(block_live(i, i, ip * ps, ps, window),
+                         pt_ref[b, ip], 0)
+
+    slot = pl.BlockSpec((1, K, G, h), lambda b, ip, *_: (b, 0, 0, 0))
+    kv = pl.BlockSpec((1, K, ps, h), lambda b, ip, *r: (page(b, ip, *r), 0,
+                                                        0, 0))
+    scale = pl.BlockSpec((1, K, ps), lambda b, ip, *r: (page(b, ip, *r), 0,
+                                                        0))
+    kernel = functools.partial(_page_grid_kernel, ps=ps, npg=npg,
+                               window=window)
+    return pl.pallas_call(
+        kernel,
+        name="paged_decode_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, npg),
+            in_specs=[slot, kv, kv] + [scale] * (len(pools) - 2),
+            out_specs=slot,
+            scratch_shapes=softmax_scratch((K, G), h),
+        ),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        interpret=interpret,
+    )(pt, idx, qg, *pools)
+
+
+def _gather_call(qg, pools, pt, idx, *, window: int, interpret: bool):
+    """The paged decode with in-kernel page gathers (module docstring)."""
+    B, K, G, h = qg.shape
+    ps, npg = pools[0].shape[2], pt.shape[1]
+    ppb = pages_per_block(npg, ps, K, h, pools[0].dtype.itemsize)
+    if len(pools) > 2:
+        # a page's scale rows are copied whole: lane-pad them to the
+        # 128-lane tile that a copy of a (K, page_size) slice needs
+        pad = ((0, 0), (0, 0), (0, -ps % LANES))
+        pools = pools[:2] + [jnp.pad(x, pad) for x in pools[2:]]
+    bufs = [pltpu.VMEM((2, ppb) + x.shape[1:], x.dtype) for x in pools]
+    slot = pl.BlockSpec((1, K, G, h), lambda b, *_: (b, 0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    kernel = functools.partial(_paged_kernel, ps=ps, ppb=ppb, window=window,
+                               n_streams=len(pools))
+    return pl.pallas_call(
+        kernel,
+        name="paged_decode_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[slot] + [hbm] * len(pools),
+            out_specs=slot,
+            scratch_shapes=bufs + [
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
+                pltpu.SMEM((1,), jnp.int32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        # the page pipeline runs on across slots: keep them in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(pt, idx, qg, *pools)
 
 
 def paged_decode_attention_kernel(q, k_pages, v_pages, page_table, index, *,
@@ -95,56 +280,12 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, page_table, index, *,
         raise ValueError("pass both k_scales and v_scales, or neither")
     B, N, h = q.shape
     K, ps = k_pages.shape[1], k_pages.shape[2]
-    npg = page_table.shape[1]
-    G = N // K
-    qg = q.reshape(B, K, G, h)
+    qg = q.reshape(B, K, N // K, h)
     pt = jnp.asarray(page_table, jnp.int32)
     idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1), (B,))
-
-    def page(b, ip, pt_ref, idx_ref):
-        # KV blocks are gathered *through the page table*: grid cell
-        # (b, kh, ip) streams physical page pt[b, ip]. Fully-masked pages
-        # are remapped to the null page 0, so their distinct-page DMA is
-        # never issued (repeated index-map outputs elide the fetch).
-        i = idx_ref[b]
-        live = block_live(i, i, ip * ps, ps, window)
-        return jnp.where(live, pt_ref[b, ip], 0)
-
-    def q_map(b, kh, ip, pt_ref, idx_ref):
-        return b, kh, 0, 0
-
-    def kv_map(b, kh, ip, pt_ref, idx_ref):
-        return page(b, ip, pt_ref, idx_ref), kh, 0, 0
-
-    def scale_map(b, kh, ip, pt_ref, idx_ref):
-        # the page's whole (K, ps) scale tile, remapped in lockstep with
-        # kv_map so a dead page's scale DMA is elided with its value DMA
-        return page(b, ip, pt_ref, idx_ref), 0, 0
-
-    in_specs = [pl.BlockSpec((1, 1, G, h), q_map),
-                pl.BlockSpec((1, 1, ps, h), kv_map),
-                pl.BlockSpec((1, 1, ps, h), kv_map)]
-    operands = [qg, k_pages, v_pages]
-    if k_scales is None:
-        kernel = functools.partial(_paged_kernel, ps=ps, npg=npg,
-                                   window=window)
-    else:
-        kernel = functools.partial(_paged_quant_kernel, ps=ps, npg=npg,
-                                   window=window)
-        in_specs += [pl.BlockSpec((1, K, ps), scale_map),
-                     pl.BlockSpec((1, K, ps), scale_map)]
-        operands += [token_scales(k_scales, ps), token_scales(v_scales, ps)]
-    out = pl.pallas_call(
-        kernel,
-        name="paged_decode_attention",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, K, npg),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, G, h), q_map),
-            scratch_shapes=softmax_scratch(G, h),
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, K, G, h), q.dtype),
-        interpret=interpret,
-    )(pt, idx, *operands)
+    pools = [k_pages, v_pages]
+    if k_scales is not None:
+        pools += [token_scales(k_scales, ps), token_scales(v_scales, ps)]
+    call = _gather_call if h % LANES == 0 else _page_grid_call
+    out = call(qg, pools, pt, idx, window=window, interpret=interpret)
     return out.reshape(B, N, h)

@@ -95,6 +95,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import serving_rules, spec_for
+from repro.kernels.decode_attention.decode_attention import live_pages
 from repro.models import kv_quant
 from repro.models import model as M
 from repro.models.layers import ModelOptions, band_len, on_tpu, pallas_on
@@ -171,6 +172,14 @@ class EngineStats:
     # factor (phase_report()["prefill_key_lane_ratio"]).
     prefill_key_lanes: int = 0       # sum of rows x banded key length
     prefill_key_lanes_full: int = 0  # rows x max_seq (old full-view core)
+    # page accounting for the paged decode-attention kernel, per decode
+    # step of the fused and reference paths: decode_kv_pages sums the live
+    # pages the kernel visits for every slot (idle slots attend position 0,
+    # one page), once per entry of the window pattern; *_full sums slots x
+    # pages per slot over the same steps, the whole page grid. Their ratio
+    # is phase_report()["decode_page_ratio"].
+    decode_kv_pages: int = 0
+    decode_kv_pages_full: int = 0
     pages_in_use: int = 0       # paged: current pool pages held by live slots
     pages_hwm: int = 0          # paged: high-water pages in use
     cache_bytes_hwm: int = 0    # paged: high-water KV bytes actually held
@@ -268,7 +277,9 @@ class EngineStats:
         plus ``engine.readback``. When prefill ran,
         ``prefill_key_lane_ratio`` is the banded core's key-axis work over
         the old full-``max_seq``-view equivalent — the paper-style phase
-        accounting for the recovered ~max_seq/S prefill factor. Per-request
+        accounting for the recovered ~max_seq/S prefill factor. On a paged
+        engine that decoded, ``decode_page_ratio`` is the share of the
+        decode kernel's page grid that held live pages. Per-request
         queue-wait and TTFT percentiles (``queue_p50/p99``, ``ttft_p50/p99``,
         seconds, present once any request reached the respective boundary)
         make a stalled fleet diagnosable from a front-end log line: a
@@ -292,6 +303,9 @@ class EngineStats:
         if self.prefill_key_lanes_full:
             rep["prefill_key_lane_ratio"] = (self.prefill_key_lanes
                                              / self.prefill_key_lanes_full)
+        if self.decode_kv_pages_full:
+            rep["decode_page_ratio"] = (self.decode_kv_pages
+                                        / self.decode_kv_pages_full)
         # per-class deadline attainment (requests with deadline_s > 0
         # only) and preemption counts, keyed by class suffix — the SLO
         # scheduler's scoreboard and the `slo` bench gate's input
@@ -1476,9 +1490,10 @@ class ServingEngine:
                 # growth may have preempted a slot under pool pressure
                 active = [s for s in range(self.n_slots)
                           if self.slots[s] is not None]
+            index = self._decode_index()
             logits, self.caches = self._decode(
                 self.params, jnp.asarray(self.tokens), self.caches,
-                jnp.asarray(self.index), pt)
+                jnp.asarray(index), pt)
         with span("engine.readback", rec):
             nxt = np.asarray(self._sample_host(logits))
         with span("engine.emit", rec):
@@ -1487,6 +1502,7 @@ class ServingEngine:
             self.stats.ticks += 1
             self.stats.device_steps += 1
             self.stats.tokens_decoded += len(active)
+            self._count_kv_pages(index, np.ones_like(index), 1)
             for s in active:
                 req = self.slots[s]
                 tok = int(nxt[s])
@@ -1559,10 +1575,11 @@ class ServingEngine:
             else:
                 done0 = np.asarray([self.slots[s] is None
                                     for s in range(self.n_slots)])
+                index0 = self._decode_index()
                 (tokens, self.caches, index, budget, done, self.key, out,
                  n_emit, steps) = self._tick(
                     self.params, jnp.asarray(self.tokens), self.caches,
-                    jnp.asarray(self.index), jnp.asarray(self.budget),
+                    jnp.asarray(index0), jnp.asarray(self.budget),
                     jnp.asarray(done0), self.key,
                     jnp.asarray(max_steps, jnp.int32), pt)
                 outs = (out, n_emit, index, budget, done, tokens, steps)
@@ -1575,8 +1592,32 @@ class ServingEngine:
             self.stats.decode_syncs += 1
             self.stats.ticks += 1
             self.stats.device_steps += int(steps_h)
+            self._count_kv_pages(index0, n_emit_h, int(steps_h))
             return self._emit(active, out_h, n_emit_h, idx_h, bud_h, done_h,
                               tok_h)
+
+    def _decode_index(self) -> np.ndarray:
+        """Decode positions for the device: an idle slot's row attends
+        position 0 alone (its cache row sinks into the null page), so the
+        paged kernel visits one page for it, not its last request's."""
+        return np.where([r is None for r in self.slots], 0,
+                        self.index).astype(np.int32)
+
+    def _count_kv_pages(self, index0, n_emit, steps: int):
+        """Add one decode stage's paged-kernel pages to the stats: slot s
+        sits at ``index0[s] + min(j, n_emit[s])`` in step j (a slot that
+        finished stays where it stopped)."""
+        if not self.paged or not steps:
+            return
+        ps, npg = self.pool.page_size, self.pool.pages_per_slot
+        pos = np.asarray(index0)[:, None] + np.minimum(
+            np.arange(steps)[None], np.asarray(n_emit)[:, None])
+        pattern = self.cfg.window_pattern
+        for w in pattern:
+            self.stats.decode_kv_pages += int(
+                live_pages(pos, ps, w, xp=np)[1].sum())
+        self.stats.decode_kv_pages_full += (steps * self.n_slots * npg
+                                            * len(pattern))
 
     def _emit(self, active: List[int], out_h, n_emit_h, idx_h, bud_h,
               done_h, tok_h) -> int:

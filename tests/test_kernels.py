@@ -104,13 +104,16 @@ def test_decode_attention_non_block_aligned(S, bk, idx, window):
 
 @pytest.mark.parametrize("B,npg,ps,N,K,h", [
     (3, 8, 16, 8, 2, 64), (2, 4, 32, 4, 4, 32), (1, 16, 8, 6, 1, 32),
+    # head_dim 128 takes the page-gather path: 9 and 33 pages are not a
+    # multiple of its block (8 pages of 32, 16 of 8)
+    (2, 9, 32, 8, 2, 128), (2, 33, 8, 4, 1, 128),
 ])
 @pytest.mark.parametrize("window", [0, 40])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_attention(B, npg, ps, N, K, h, window, dtype):
-    """Paged kernel (page-table gather via scalar-prefetched index map)
-    against the gather-then-dense oracle, with a scrambled page table so
-    physical order != logical order."""
+    """Paged kernel (pages gathered through the scalar-prefetched page
+    table) against the gather-then-dense oracle, with a scrambled page
+    table so physical order != logical order."""
     P = B * npg + 3
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, N, h), dtype)
@@ -125,6 +128,84 @@ def test_paged_decode_attention(B, npg, ps, N, K, h, window, dtype):
     exp = dref.paged_decode_attention_ref(q, kp, vp, pt, idx, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), **_tol(dtype))
+
+
+def _quantized(kp, vp, kv):
+    """bf16 pools, or int8/fp8 codes with their per-(page, head) scales
+    (``"int8"``, ``"fp8"``) or per-token scales (``"int8_token"``)."""
+    from repro.models import kv_quant
+    if kv == "bf16":
+        return kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16), {}
+    dt = jnp.float8_e4m3fn if kv == "fp8" else jnp.int8
+    gran = "token" if kv.endswith("_token") else "head"
+    kq, ksc = kv_quant.quantize_page_rows(kp, dt, gran)
+    vq, vsc = kv_quant.quantize_page_rows(vp, dt, gran)
+    return kq, vq, dict(k_scales=ksc, v_scales=vsc)
+
+
+@pytest.mark.parametrize("window", [0, 40])
+@pytest.mark.parametrize("kv", ["bf16", "int8", "fp8", "int8_token"])
+def test_paged_decode_attention_block_edges(window, kv):
+    """The page-gather path at the edges of its page blocks (16 pages of
+    16, 256 positions), K = 1: an idle slot at position 0, the last and
+    first lane of a block, a slot inside its first page and one whose
+    window leaves its whole first block dead (319 - 40 + 1 = 280 lies in
+    page 17); bf16, int8 and fp8 pools, per-(page, head) and per-token
+    scales, against the dequantizing oracle."""
+    B, npg, ps, N, K, h = 5, 20, 16, 4, 1, 128
+    P = B * npg + 1
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, N, h))
+    kp, vp, scales = _quantized(jax.random.normal(ks[1], (P, K, ps, h)),
+                                jax.random.normal(ks[2], (P, K, ps, h)), kv)
+    perm = jax.random.permutation(ks[3], jnp.arange(1, P))
+    pt = perm.reshape(B, npg).astype(jnp.int32)
+    idx = jnp.asarray([0, 255, 256, 7, 319], jnp.int32)
+    out = paged_decode_attention(q, kp, vp, pt, idx, window=window,
+                                 interpret=True, **scales)
+    if scales:
+        exp = dref.paged_decode_attention_quant_ref(
+            q, kp, vp, scales["k_scales"], scales["v_scales"], pt, idx,
+            window=window)
+    else:
+        exp = dref.paged_decode_attention_ref(q, kp, vp, pt, idx,
+                                              window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                               atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("h", [64, 128])
+@pytest.mark.parametrize("window", [0, 40])
+def test_paged_decode_bit_equals_dense_at_page_blocks(h, window):
+    """The partition contract: over an identity page table the paged kernel
+    equals the dense decode kernel at ``bk = page_size`` bit for bit, on
+    both of its paths (page grid at head_dim 64, page gather at 128)."""
+    B, S, N, K, ps = 3, 256, 8, 2, 32
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (B, N, h))
+    kc = jax.random.normal(ks[1], (B, K, S, h))
+    vc = jax.random.normal(ks[2], (B, K, S, h))
+    npg = S // ps
+    pt = jnp.arange(B * npg, dtype=jnp.int32).reshape(B, npg)
+    idx = jnp.asarray([255, 32, 31], jnp.int32)
+    dense = decode_attention(q, kc, vc, idx, window=window, bk=ps,
+                             interpret=True)
+    paged = paged_decode_attention(q, _pages(kc, ps), _pages(vc, ps), pt,
+                                   idx, window=window, interpret=True)
+    assert jnp.array_equal(paged, dense)
+
+
+@pytest.mark.parametrize("window", [0, 1, 40, 64])
+def test_live_pages_is_the_block_live_run(window):
+    """``live_pages`` names exactly the pages ``block_live`` keeps."""
+    from repro.kernels.decode_attention.decode_attention import (block_live,
+                                                                 live_pages)
+    ps, npg = 16, 20
+    index = np.arange(npg * ps)[:, None]
+    page = np.arange(npg)[None]
+    live = np.asarray(block_live(index, index, page * ps, ps, window))
+    first, n = live_pages(index, ps, window, xp=np)
+    assert np.array_equal(live, (page >= first) & (page < first + n))
 
 
 def test_layers_decode_routes_through_kernels():

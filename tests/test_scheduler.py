@@ -503,6 +503,24 @@ def test_phase_report_percentiles_and_ttft(opts):
         assert r.ttft_s >= r.queue_s >= 0
 
 
+def test_decode_kv_pages_count_live_pages(opts):
+    """EngineStats.decode_kv_pages / decode_kv_pages_full on a small paged
+    engine equal the live pages worked out by hand from the positions."""
+    cfg, params = reduced_params("smollm-135m")
+    rng = np.random.default_rng(11)
+    prompt = rng.integers(0, cfg.vocab_size, 13, dtype=np.int32)
+    _, eng = _streams(cfg, opts, params, [(prompt, 10)], paged=True,
+                      page_size=8)
+    # 2 slots of 64 positions, 8 pages each. The request decodes 9 tokens
+    # at positions 13..21: pages 0-1 are live at 13-15 (3 steps x 2),
+    # pages 0-2 at 16-21 (6 steps x 3). The idle slot attends position 0,
+    # one page a step.
+    st = eng.stats
+    assert st.decode_kv_pages == 3 * 2 + 6 * 3 + 9 * 1
+    assert st.decode_kv_pages_full == 9 * 2 * 8
+    assert st.phase_report()["decode_page_ratio"] == pytest.approx(33 / 144)
+
+
 def test_realtime_jumps_best_effort_backlog(opts):
     """A realtime control request submitted behind a best-effort backlog is
     admitted class-first, finishes first, and scores its deadline in the

@@ -107,6 +107,24 @@ def test_paged_decode_kernel_compiles(one_chip, kv):
         sharding=one_chip))
 
 
+# the fleet cell's decode batch (32 slots, 32 pages of 32) at molmoact-7b's
+# heads (the page-gather path) and at internvl2-1b's head_dim 64 (the page
+# grid), for every pool dtype
+@pytest.mark.parametrize("heads", [(N, K, H), (14, 2, 64)])
+@pytest.mark.parametrize("kv", sorted(SCALES))
+def test_paged_decode_kernel_compiles_for_the_fleet(one_chip, kv, heads):
+    n, k, h = heads
+    slots, pool = 32, 1 + 32 * NPG
+    dt = BF16 if kv == "bf16" else jnp.int8
+    scales = {"bf16": [], "int8_head": [((pool, k), jnp.float32)] * 2,
+              "int8_token": [((pool, k, PAGE), jnp.float32)] * 2}[kv]
+    _assert_kernel(_compile(
+        _paged(paged_decode_attention_kernel, kv), ((slots, n, h), BF16),
+        *[((pool, k, PAGE, h), dt)] * 2, *scales,
+        ((slots, NPG), jnp.int32), ((slots,), jnp.int32),
+        sharding=one_chip))
+
+
 # chunk 64, and 128: the largest chunk chip_smoke.py prefills with. The
 # chunk kernels hold a whole chunk's query rows and softmax state in VMEM.
 @pytest.mark.parametrize("chunk", [64, 128])
