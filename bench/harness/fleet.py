@@ -67,6 +67,9 @@ class Fleet:
         self.window_close: Optional[float] = None
         self.stopping = False
         self._uid = 0
+        # (wall, process CPU) seconds at each poll of the event loop in the
+        # window: where the loop stood still, and whether the process ran
+        self.polls: List[tuple] = []
 
     def frame(self) -> np.ndarray:
         """The next unique camera frame: a pooled frame with this step's
@@ -113,6 +116,16 @@ class Fleet:
                 on_close()
             k += 1
 
+    def longest_pause(self):
+        """The longest stretch of the window in which the event loop did
+        not come round, as (seconds, process CPU seconds in it, seconds
+        after the window opened); None before two polls."""
+        if len(self.polls) < 2:
+            return None
+        (w0, c0), (w1, c1) = max(zip(self.polls, self.polls[1:]),
+                                 key=lambda p: p[1][0] - p[0][0])
+        return w1 - w0, c1 - c0, w0 - self.window_open
+
     async def run(self, fe, seconds: float, on_open: Callable,
                   on_close: Callable, t0: float):
         """Drive the fleet through warm-up and the window; cut the steps
@@ -124,6 +137,8 @@ class Fleet:
             done = [t for t in tasks if t.done()]
             for t in done:
                 t.result()          # a robot that failed fails the run
+            if self.window_open is not None:
+                self.polls.append((time.perf_counter(), time.process_time()))
             await asyncio.sleep(0.05)
         for t in tasks:
             if t.done():

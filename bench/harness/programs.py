@@ -1,17 +1,17 @@
 """Which device events belong to which stage of the served path.
 
 On the TPU each execution of a jitted program is an event of the device's
-``XLA Modules`` line, and the operations it ran are events of ``XLA Ops``
-inside it (a ``while`` op holds the ops of its body). The engine's
-programs carry no names of their own yet (the tick is ``jit__unknown``,
-vision and chunk are both ``jit__lambda``), so a program execution is
-told apart by what ran inside it:
+``XLA Modules`` line, named after the program and its fingerprint
+(``jit_serve_tick(1234...)``), and the operations it ran are events of
+``XLA Ops`` inside it (a ``while`` op holds the ops of its body). The
+engine's stage programs carry names of their own, on one chip and on a
+mesh alike, and a stage is matched by that name:
 
-- ``tick``: it ran the paged decode-attention kernel;
-- ``chunk``: it ran the paged chunk-prefill kernel;
-- ``vision``: it read the vision tower's weights (XLA names a jitted
-  argument's leaves after their path, ``p__vision__...``).
+- ``tick``: ``jit_serve_tick``, the fused decode tick;
+- ``chunk``: ``jit_serve_chunk``, one chunk of chunked prefill;
+- ``vision``: ``jit_serve_vision``, the vision tower.
 
+Any other program keeps its own name (up to the fingerprint) as its stage.
 Pallas kernels appear as custom calls named after their wrappers
 (``paged_decode_attention``, ``paged_chunk_prefill_attention``).
 """
@@ -25,8 +25,9 @@ from harness.trace import Event
 
 DECODE_KERNEL = re.compile(r"^%paged_decode_attention[.\s]")
 CHUNK_KERNEL = re.compile(r"^%paged_chunk_prefill_attention[.\s]")
-VISION_WEIGHTS = re.compile(r"p__vision__")
-STAGES = ("tick", "chunk", "vision")
+PROGRAMS = {"jit_serve_tick": "tick", "jit_serve_chunk": "chunk",
+            "jit_serve_vision": "vision"}
+STAGES = tuple(PROGRAMS.values())
 
 
 def short(op_name: str) -> str:
@@ -36,33 +37,26 @@ def short(op_name: str) -> str:
     return f"{head.lstrip('%')} {rest.split('{')[0]}".strip()
 
 
+def stage_of(module: Event) -> str:
+    """The stage a program execution belongs to, by the program's name."""
+    name = module.name.split("(")[0]
+    return PROGRAMS.get(name, name)
+
+
 def assign(modules: List[Event], ops: List[Event]
            ) -> List[Tuple[Event, str, List[Event]]]:
-    """Each program execution with its stage and the ops it ran. A module
-    of none of the three stages keeps its own name (up to the fingerprint)
-    as its stage."""
+    """Each program execution with its stage and the ops it ran."""
     starts = [e.start for e in ops]
-    out = []
-    for m in modules:
-        lo = bisect.bisect_left(starts, m.start)
-        hi = bisect.bisect_left(starts, m.end)
-        inner = ops[lo:hi]
-        if any(DECODE_KERNEL.search(e.name) for e in inner):
-            stage = "tick"
-        elif any(CHUNK_KERNEL.search(e.name) for e in inner):
-            stage = "chunk"
-        elif any(VISION_WEIGHTS.search(e.name) for e in inner):
-            stage = "vision"
-        else:
-            stage = m.name.split("(")[0]
-        out.append((m, stage, inner))
-    return out
+    return [(m, stage_of(m), ops[bisect.bisect_left(starts, m.start):
+                                  bisect.bisect_left(starts, m.end)])
+            for m in modules]
 
 
-def stages(modules: List[Event], ops: List[Event]) -> Dict[str, List[Event]]:
+def stages(modules: List[Event]) -> Dict[str, List[Event]]:
     """Program executions by stage: ``tick``, ``chunk``, ``vision``."""
     out: Dict[str, List[Event]] = {s: [] for s in STAGES}
-    for m, stage, _ in assign(modules, ops):
+    for m in modules:
+        stage = stage_of(m)
         if stage in out:
             out[stage].append(m)
     return out

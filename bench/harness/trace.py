@@ -18,8 +18,16 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Tuple
 
-COLLECTIVE = re.compile(r"all-reduce|all-gather|reduce-scatter|"
-                        r"collective-permute|all-to-all|psum", re.I)
+# a collective by its own opcode where the event gives the op's text
+# (``%psum.24 = bf16[...] all-reduce(%fusion.204)``: the instruction's name
+# may come from any JAX primitive, and a fusion fed by a psum names it among
+# its operands), by its own name where the event gives a name alone
+COLLECTIVE_OP = re.compile(
+    r"^%?[\w.-]+ = (?:\(.*?\)|\S+) (all-reduce|all-gather|reduce-scatter|"
+    r"collective-permute|all-to-all)(-start|-done)?\(")
+COLLECTIVE_NAME = re.compile(
+    r"^%?(all[-_]reduce|all[-_]gather|reduce[-_]scatter|"
+    r"collective[-_]permute|all[-_]to[-_]all|psum|ppermute)", re.I)
 
 
 @dataclass
@@ -125,8 +133,14 @@ def by_name(events: List[Event]) -> Dict[str, float]:
     return out
 
 
+def is_collective(name: str) -> bool:
+    if " = " in name:
+        return bool(COLLECTIVE_OP.match(name))
+    return bool(COLLECTIVE_NAME.match(name))
+
+
 def collective_seconds(events: List[Event]) -> float:
-    return sum(e.dur for e in events if COLLECTIVE.search(e.name))
+    return sum(e.dur for e in events if is_collective(e.name))
 
 
 def host_span_at(host: List[Event], t: float, prefix: str = "bench."

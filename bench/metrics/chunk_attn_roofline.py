@@ -1,7 +1,8 @@
 """Share of its roofline that the paged chunk-prefill attention kernel
-reaches: the least time for the causal attention of every prompt whose
-prefill finished in the traced window (operations bound it), over the
-kernel's device time."""
+reaches: the least time one chip needs for the causal attention of every
+prompt whose prefill finished in the traced window, all heads (operations
+bound it), over the kernel's device time summed over the cell's chips,
+each of which holds its share of the heads."""
 from harness import shapes
 from harness.common import kernel_events, prefilled_steps, roofline_pct
 

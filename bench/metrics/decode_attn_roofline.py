@@ -1,7 +1,8 @@
 """Share of its roofline that the paged decode-attention kernel reaches:
-the least time the chip needs for the KV pages read (and the attention
-operations) of every token decoded in the traced window, over the
-kernel's device time. Memory bounds it at these shapes."""
+the least time one chip needs for the KV pages read (and the attention
+operations) of every token decoded in the traced window, all KV heads,
+over the kernel's device time summed over the cell's chips, each of which
+holds its share of the heads. Memory bounds it at these shapes."""
 from harness import shapes
 from harness.common import decoded_positions, kernel_events, roofline_pct
 

@@ -1,6 +1,8 @@
-"""Share of the chip's HBM bandwidth the decode steps use: per device step
-every weight, the KV pages of each live row and the logits written and
-read, over the fused ticks' device time at the chip's peak bandwidth."""
+"""Share of HBM bandwidth the decode steps use: per device step every
+weight of the whole model, the KV pages of each live row and the logits
+written and read, over the fused ticks' device time summed over the
+cell's chips at one chip's peak bandwidth. On a mesh that splits the
+weights and pages evenly this is each chip's own share."""
 from harness import shapes
 from harness.common import decoded_positions, stage_events
 

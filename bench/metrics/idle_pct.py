@@ -1,12 +1,13 @@
 """Share of the traced window in which no operation runs on the device,
 averaged over the chips the cell uses."""
 from harness import trace as T
+from harness.common import chips
 
 
 def read(run):
-    w = run.window
-    if w.trace is None or not w.trace.ops:
+    w, devs = run.window, chips(run)
+    if not devs:
         return None
     a, b = w.trace_span
-    busy = sum(T.busy(ops, a, b) for ops in w.trace.ops.values())
-    return 100.0 * (1.0 - busy / (len(w.trace.ops) * (b - a)))
+    busy = sum(T.busy(w.trace.ops[d], a, b) for d in devs)
+    return 100.0 * (1.0 - busy / (len(devs) * (b - a)))

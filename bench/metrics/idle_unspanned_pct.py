@@ -1,12 +1,14 @@
 """Share of the traced window in which the first chip is idle and no span
 of the program is open: the idle that the program cannot name, so
-``idle_pct`` less this is the idle it can. The program's spans come from
-its recorder (``Request.trace``) on ``time.perf_counter()``; they are moved
-onto the trace's clock by the window's close, which both clocks mark
-microseconds apart (``Window.close`` and the end of ``bench.window``).
-None where the program records no spans or the ring dropped some of the
-window's."""
+``idle_pct`` less this is the idle it can. Only the first chip is read:
+the program's spans are the host's, and on a mesh every chip waits on the
+same host. The program's spans come from its recorder (``Request.trace``)
+on ``time.perf_counter()``; they are moved onto the trace's clock by the
+window's close, which both clocks mark microseconds apart
+(``Window.close`` and the end of ``bench.window``). None where the
+program records no spans or the ring dropped some of the window's."""
 from harness import trace as T
+from harness.common import chips
 
 
 def read(run):
@@ -15,7 +17,8 @@ def read(run):
                 if getattr(s.request, "trace", None) is not None), None)
     if rec is None or not rec.intact_since(w.open):
         return None
-    if w.trace is None or not w.trace.ops:
+    devs = chips(run)
+    if not devs:
         return None
     a, b = w.trace_span
     shift = b - w.close
@@ -23,7 +26,7 @@ def read(run):
                      for s in rec.spans() if s.t1 + shift > a
                      and s.t0 + shift < b])
     unnamed, j = 0.0, 0
-    for g0, g1 in sorted(T.gaps(w.trace.ops[min(w.trace.ops)], a, b)):
+    for g0, g1 in sorted(T.gaps(w.trace.ops[devs[0]], a, b)):
         unnamed += g1 - g0
         while j < len(named) and named[j][1] <= g0:
             j += 1

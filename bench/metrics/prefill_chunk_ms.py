@@ -1,5 +1,6 @@
 """Device milliseconds per run of the chunked-prefill program (one chunk
-of ``chunk_size`` rows), from the trace."""
+of ``chunk_size`` rows), from the trace: the mean over every run on every
+chip of the cell, which is the mean over the chips."""
 from harness.common import stage_events
 
 

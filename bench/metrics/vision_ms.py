@@ -1,4 +1,6 @@
-"""Device milliseconds per run of the vision program, from the trace."""
+"""Device milliseconds per run of the vision program, from the trace: the
+mean over every run on every chip of the cell, which is the mean over the
+chips."""
 from harness.common import stage_events
 
 

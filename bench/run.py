@@ -46,6 +46,7 @@ sys.path[:0] = [str(BENCH), str(ROOT / "src")]
 import numpy as np  # noqa: E402
 
 from harness import spec  # noqa: E402
+from harness.common import chips  # noqa: E402
 
 TRACE_DIR = ROOT / ".bench_trace"
 
@@ -361,14 +362,16 @@ def per_layer(bm, wl, run: Run) -> dict:
     return out
 
 
-def breakdown(w: Window) -> dict:
+def breakdown(run: Run) -> dict:
     """The ten device operations that took most time (leaf ops, named by
     stage, op and shape), and the ten longest idle gaps, named by the
-    benchmark's host span around them and the stage that ran before."""
+    benchmark's host span around them and the stage that ran before: all
+    read on the first chip, which on a mesh runs what the others run."""
     from harness import programs
     from harness import trace as T
+    w = run.window
     a, b = w.trace_span
-    dev = min(w.trace.ops)
+    dev = chips(run)[0]
     ops = T.clip(w.trace.ops[dev], a, b)
     ran = programs.assign(T.clip(w.trace.modules.get(dev, []), a, b), ops)
     spent: dict = {}
@@ -387,14 +390,24 @@ def breakdown(w: Window) -> dict:
     return {"device_ops": [[n, s] for n, s in top], "idle_gaps": idle}
 
 
-def device_busy(w: Window) -> float:
+def device_busy(run: Run) -> float:
+    """Seconds of the traced window in which an operation ran, averaged
+    over the cell's chips."""
     from harness import trace as T
+    w = run.window
     a, b = w.trace_span
-    return float(np.mean([T.busy(ops, a, b)
-                          for ops in w.trace.ops.values()]))
+    return float(np.mean([T.busy(w.trace.ops[d], a, b)
+                          for d in chips(run)]))
 
 
 # -- main --------------------------------------------------------------------
+
+def log_checks(checks: dict):
+    """Each number compared, beside its limit: the run's last lines on
+    standard error."""
+    for name, v in checks.items():
+        log(f"check {name}: {v['value']!r} (limit {v['limit']!r})")
+
 
 def prepare(wl_name: str, rehearse: bool):
     bm = spec.benchmark()
@@ -483,6 +496,10 @@ def main(argv=None) -> int:
     log(f"window {w.close - w.open:.3f} s, {len(fleet.counted())} steps "
         f"completed; compilations inside the window: {w.compiles}; "
         f"counters {w.counters}; steps cut at the close: {w.cut}")
+    pause = fleet.longest_pause()
+    if pause is not None:
+        log("longest stand-still of the event loop: {:.3f} s, {:.3f} s of "
+            "process CPU in it, {:.1f} s into the window".format(*pause))
     shape = engine_shape(eng, prompt)
     del eng
     gc.collect()
@@ -499,6 +516,11 @@ def main(argv=None) -> int:
                       compiles_in_window=w.compiles)
     else:
         run = Run(c, mix, shape, w, pk, len(devices))
+        if traced and not chips(run):
+            log("the trace of one of the cell's chips does not cover the "
+                "window: no device reading")
+            log_checks(checks)
+            return 1
         if traced:
             metrics = per_layer(bm, wl, run)
         else:
@@ -509,14 +531,13 @@ def main(argv=None) -> int:
         device = {"platform": dev.platform, "kind": dev.device_kind,
                   "count": len(devices), "memory_peak_bytes": w.peak_bytes}
         if traced:
-            device.update(busy_s=device_busy(w),
+            device.update(busy_s=device_busy(run),
                           window_s=w.trace_span[1] - w.trace_span[0])
         result.update(metrics=metrics, device=device)
         if traced:
-            result["breakdown"] = breakdown(w)
+            result["breakdown"] = breakdown(run)
     result["checks"] = checks
-    for name, v in checks.items():
-        log(f"check {name}: {v['value']!r} (limit {v['limit']!r})")
+    log_checks(checks)
     print(json.dumps(result), flush=True)
     return 0
 

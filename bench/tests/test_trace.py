@@ -1,7 +1,14 @@
-"""The trace reduction, on hand-made intervals and on a small trace
+"""The trace reduction, on hand-made intervals, on a small trace
 recorded once from a CPU run (``data/cpu_window.xplane.pb``: three calls
 of a jitted ``tanh(x @ x).sum()``, each inside a ``bench.submit`` span, in
-one ``bench.window`` span)."""
+one ``bench.window`` span), and on the program executions of traced chip
+windows (``data/chip_modules.json.gz``: each chip's ``XLA Modules``
+events inside the window, one chip of the two cells and all four of a
+``model=4`` mesh, with the stage that the reduction gave each when it
+still told the stages apart by the kernels and vision weights that ran
+inside them)."""
+import gzip
+import json
 from pathlib import Path
 
 import pytest
@@ -9,6 +16,7 @@ import pytest
 from harness import trace as T
 
 DATA = Path(__file__).parent / "data" / "cpu_window.xplane.pb"
+CHIP = Path(__file__).parent / "data" / "chip_modules.json.gz"
 
 
 def ev(name, a, b):
@@ -27,8 +35,19 @@ def test_union_busy_and_gaps_by_hand():
 
 def test_collectives_and_host_spans_by_hand():
     ops = [ev("all-reduce.3", 0, 1), ev("fusion.1", 1, 3),
-           ev("all-gather-start", 3, 3.5)]
-    assert T.collective_seconds(ops) == pytest.approx(1.5)
+           ev("all-gather-start", 3, 3.5),
+           ev("%psum.24 = bf16[32,1,3584]{2,0,1} all-reduce(%fusion.204)",
+              4, 4.25),
+           ev("%fusion.210 = bf16[32,1,3584]{2,0,1} fusion(%psum.24)", 5, 7),
+           # instructions named after the JAX primitive, not the opcode
+           ev("%all_gather.3 = bf16[32,152064]{1,0:T(8,128)(2,1)} "
+              "all-gather(%dot.7), dimensions={1}", 7, 7.5),
+           ev("%reduce_scatter.1 = (bf16[8]{0:T(256)}, bf16[8]{0:T(256)}) "
+              "reduce-scatter-start(%a, %b)", 7.5, 7.75),
+           ev("%ppermute.2 = bf16[4]{0} collective-permute(%c)", 8, 8.125),
+           ev("ppermute.5", 9, 9.125),
+           ev("%copy.1 = bf16[4]{0} copy(%all_gather.3)", 10, 12)]
+    assert T.collective_seconds(ops) == pytest.approx(2.75)
     host = [ev("bench.window", 0, 10), ev("bench.submit", 2, 3),
             ev("other", 0, 10)]
     assert T.host_span_at(host, 2.5) == "bench.submit"
@@ -60,10 +79,12 @@ def test_recorded_cpu_trace():
         sum(e.dur for e in ops if e.name == "dot_general.1"))
 
 
-def test_programs_told_apart_by_what_ran_inside():
+def test_programs_told_apart_by_name():
+    # the stage programs by their own names; the ops that ran inside each
+    # are its own, and a program of no stage keeps its name
     from harness import programs
-    mods = [ev("jit__unknown(1)", 0, 10), ev("jit__lambda(2)", 10, 14),
-            ev("jit__lambda(3)", 14, 15), ev("jit__argmax(4)", 15, 16)]
+    mods = [ev("jit_serve_tick(1)", 0, 10), ev("jit_serve_chunk(2)", 10, 14),
+            ev("jit_serve_vision(3)", 14, 15), ev("jit__argmax(4)", 15, 16)]
     ops = [ev("%while.1 = (s32[]) while(...)", 0, 9),
            ev("%paged_decode_attention.8 = bf16[32,4,7,128]{3} "
               "custom-call(...)", 1, 3),
@@ -76,8 +97,35 @@ def test_programs_told_apart_by_what_ran_inside():
     ran = programs.assign(mods, ops)
     assert [s for _, s, _ in ran] == ["tick", "chunk", "vision",
                                       "jit__argmax"]
-    assert programs.stages(mods, ops)["tick"] == [mods[0]]
+    assert [len(inner) for _, _, inner in ran] == [3, 1, 1, 1]
+    assert programs.stages(mods)["tick"] == [mods[0]]
     assert [e.name[:9] for e in programs.leaves(ops)] == [
         "%paged_de", "%fusion.2", "%paged_ch", "%fusion.1", "%reduce.1"]
     assert programs.short(ops[2].name) == "fusion.2 bf16[32,18944]"
     assert len(programs.kernel_ops(ops, programs.DECODE_KERNEL)) == 1
+    # the name decides, not what ran inside: a spec tick runs the chunk
+    # kernel and is no chunk, and a tick cut short by the window is a tick
+    assert programs.stage_of(ev("jit_serve_spec_tick(5)", 0, 1)) == \
+        "jit_serve_spec_tick"
+    assert programs.stages([ev("jit_serve_tick(1)", 0, 1)])["tick"]
+
+
+def test_recorded_chip_windows_get_the_same_stages():
+    from harness import programs
+    with gzip.open(CHIP, "rt") as f:
+        cells = json.load(f)["cells"]
+    for cell, chips in cells.items():
+        counts = []
+        for chip, rows in chips.items():
+            mods = [ev(name, a, b) for name, a, b, _ in rows]
+            got = [stage for _, stage, _ in programs.assign(mods, [])]
+            # on a mesh the vision weights' ops carry other names, and the
+            # old rule left the vision program its module's name
+            want = ["vision" if stage == "jit_serve_vision" else stage
+                    for *_, stage in rows]
+            assert got == want, (cell, chip)
+            counts.append({s: len(m) for s, m in
+                           programs.stages(mods).items()})
+            assert all(counts[-1].values()), (cell, chip, counts[-1])
+        # on a mesh every chip runs every program
+        assert all(c == counts[0] for c in counts), (cell, counts)
